@@ -370,7 +370,10 @@ def _pipecg_engine(A, b, x0=None, *, maxiter=100, tol=0.0, M=None,
             vecs, gamma_new, delta_new, rr, dev = jax.lax.cond(
                 jnp.any(trip), _replace, lambda op: op,
                 (vecs, gamma_new, delta_new, rr, dev))
-        done = st["done"] | (rr <= tol2)
+        # gamma = <r, M r> (or <r, A M r>) is exactly zero only when the
+        # recurrence has nothing left to reduce: stop there (the happy
+        # breakdown of CG) instead of forming 0/0 in the next beta
+        done = st["done"] | (rr <= tol2) | (gamma_new == 0)
         mask = st["done"]
         if not policy.is_default:
             # breakdown guard: a demoted recurrence that decays past its

@@ -38,7 +38,6 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.krylov.base import SolveResult, make_psum_dot
 from repro.core.krylov.operators import DiaMatrix
@@ -71,38 +70,10 @@ def _noise_tick(noise: NoiseHook, axis_name, dtype):
     # schedule a flattened 1D mesh of equal size would
     idx = jnp.zeros((), jnp.int32)
     for nm in names:
-        idx = idx * _axis_size(nm) + jax.lax.axis_index(nm)
+        idx = idx * jax.lax.axis_size(nm) + jax.lax.axis_index(nm)
     tick = io_callback(noise, jax.ShapeDtypeStruct((), jnp.float32), idx,
                        ordered=False)
     return tick.astype(dtype)
-
-
-def _axis_size(axis_name) -> int:
-    """Static size of a mapped axis (or product over a tuple of axes).
-
-    ``jax.lax.axis_size`` only exists in newer JAX; older 0.4.x releases
-    expose the information through the (private) axis env, which shard_map
-    populates.  The private fallback is import-guarded so a JAX that has
-    removed the internal fails with an actionable message instead of an
-    AttributeError from deep inside tracing.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    try:
-        from jax._src.core import get_axis_env
-    except (ImportError, AttributeError) as e:
-        raise RuntimeError(
-            "cannot determine the mapped axis size: this JAX version has "
-            "neither jax.lax.axis_size (added in newer releases) nor the "
-            "legacy jax._src.core.get_axis_env internal it superseded; "
-            "upgrade JAX (or pin a 0.4.x release that still ships the "
-            "axis env)") from e
-    env = get_axis_env()
-    names = axis_name if isinstance(axis_name, tuple) else (axis_name,)
-    size = 1
-    for nm in names:
-        size *= env.axis_size(nm)
-    return size
 
 
 def halo_exchange_cols(x: jnp.ndarray, halo: int, axis_name: str = AXIS):
@@ -113,7 +84,7 @@ def halo_exchange_cols(x: jnp.ndarray, halo: int, axis_name: str = AXIS):
     ring neighbors; chain-boundary devices receive zeros (matches the
     zero padding of DIA bands at the matrix boundary).
     """
-    n_dev = _axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     if n_dev == 1 or halo == 0:
         z = jnp.zeros(x.shape[:-1] + (halo,), x.dtype)
         return z, z
@@ -154,7 +125,7 @@ def halo_exchange_compressed(x: jnp.ndarray, halo: int, axis_name: str,
     """
     from repro.distributed import compression as comp
 
-    n_dev = _axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     if n_dev == 1 or halo == 0:
         z = jnp.zeros(x.shape[:-1] + (halo,), x.dtype)
         return z, z, jnp.zeros_like(ef_l), jnp.zeros_like(ef_r)
@@ -506,7 +477,7 @@ def _exchange_along(v: jnp.ndarray, w: int, axis_name: str, axis: int):
     chain-boundary devices receive zeros — matching the zero band
     coefficients a DIA operator carries at the matrix boundary.
     """
-    n_dev = _axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     if n_dev == 1 or w == 0:
         shp = list(v.shape)
         shp[axis] = w
@@ -1347,8 +1318,8 @@ def _engine_solve_2d(name, ip, A: DiaMatrix, b, mesh: Mesh, eng, *,
 
     out_specs = SolveResult(x=P(ay, ax), iters=P(), res_norm=P(),
                             res_history=P(), detect_history=P())
-    fn = shard_map(run, mesh=mesh, in_specs=(P(None, ay, ax), P(ay, ax)),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=(P(None, ay, ax), P(ay, ax)),
+                       out_specs=out_specs, check_vma=False)
     res = fn(bands2, b2)
     return res._replace(x=res.x.reshape(b.shape))
 
@@ -1395,9 +1366,9 @@ def _engine_solve_bsr(name, ip, A, b, mesh: Mesh, eng, *, noise=None,
 
     out_specs = SolveResult(x=P(axis, None), iters=P(), res_norm=P(),
                             res_history=P(), detect_history=P())
-    fn = shard_map(run, mesh=mesh,
-                   in_specs=(P(None, axis, None, None), P(axis, None)),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(P(None, axis, None, None), P(axis, None)),
+                       out_specs=out_specs, check_vma=False)
     res = fn(bblocks, b2)
     return res._replace(x=res.x.reshape(b.shape))
 
@@ -1507,8 +1478,8 @@ def _distributed_engine_solve(solver, A, b, mesh: Mesh, eng, *,
                           gamma_prev=P(), alpha_prev=P(), done=P()))
     else:
         out_specs = res_specs
-    fn = shard_map(run, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=out_specs, check_vma=False)
     return fn(A.bands, b, *extra)
 
 
@@ -1640,6 +1611,6 @@ def distributed_solve(solver: Callable, A: DiaMatrix, b: jnp.ndarray,
         return solver(mv, b_local, dot=dot, **extra_kw, **solver_kw)
 
     out_specs = SolveResult(x=spec_v, iters=P(), res_norm=P(), res_history=P())
-    fn = shard_map(run, mesh=mesh, in_specs=(spec_b, spec_v),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=(spec_b, spec_v),
+                       out_specs=out_specs, check_vma=False)
     return fn(A.bands, b)

@@ -230,6 +230,7 @@ def run_abft_exec(spec, timeout_s: float = 900.0) -> Dict:
                   for m in spec.abft_magnitudes],
     }
     env = os.environ.copy()
+    env["JAX_PLATFORMS"] = "cpu"  # forced host devices, one per shard
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={spec.abft_shards} "
         + env.get("XLA_FLAGS", "")).strip()

@@ -180,6 +180,7 @@ def run_fault_exec(spec, timeout_s: float = 900.0) -> Dict:
     }
     max_p = max(spec.fault_shard_counts)
     env = os.environ.copy()
+    env["JAX_PLATFORMS"] = "cpu"  # forced host devices, one per shard
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={max_p} "
                         + env.get("XLA_FLAGS", "")).strip()
     # the worker must resolve the same repro package as this process

@@ -239,6 +239,7 @@ def run_geometry_exec(spec, timeout_s: float = 900.0) -> Dict:
     }
     max_p = max(math.prod(c["grid"]) for c in cells)
     env = os.environ.copy()
+    env["JAX_PLATFORMS"] = "cpu"  # forced host devices, one per shard
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={max_p} "
                         + env.get("XLA_FLAGS", "")).strip()
     # the worker must resolve the same repro package as this process

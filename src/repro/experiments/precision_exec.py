@@ -361,6 +361,7 @@ def run_precision_exec(spec, timeout_s: float = 900.0) -> Dict:
            "maxiter": spec.precision_maxiter, "seed": spec.seed,
            "cells": cells}
     env = os.environ.copy()
+    env["JAX_PLATFORMS"] = "cpu"  # forced host devices, one per shard
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={spec.precision_shards} "
         + env.get("XLA_FLAGS", "")).strip()

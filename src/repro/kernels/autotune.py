@@ -106,12 +106,13 @@ def save_cache(path: str = DEFAULT_CACHE_PATH) -> str:
 
 
 def modeled_words(n: int, block: int, *, words_per_row: float,
-                  resident_words: float = 0.0) -> float:
+                  resident_words: float = 0.0,
+                  step_words: float = 0.0) -> float:
     """Modeled HBM words moved by a tiled sweep over ``n`` padded rows."""
     n_pad = -(-n // block) * block
     steps = n_pad // block
     return (n_pad * words_per_row + resident_words
-            + steps * STEP_OVERHEAD_WORDS)
+            + steps * (STEP_OVERHEAD_WORDS + step_words))
 
 
 def _measure(thunk: Callable[[], jax.Array], reps: int = 5) -> float:
@@ -128,7 +129,7 @@ def _measure(thunk: Callable[[], jax.Array], reps: int = 5) -> float:
 
 def best_block(kind: str, n: int, dtype, *,
                words_per_row: float, resident_words: float = 0.0,
-               min_block: int = 1,
+               step_words: float = 0.0, min_block: int = 1,
                candidates: Sequence[int] = DEFAULT_CANDIDATES,
                probe: Optional[Callable[[int], Callable[[], jax.Array]]] = None,
                backend: Optional[str] = None,
@@ -141,6 +142,8 @@ def best_block(kind: str, n: int, dtype, *,
                       accum dtype (storage-dtype operands count their
                       itemsize ratio — see ops.py::_rel_words)
     resident_words  — words fetched once per sweep regardless of block
+    step_words      — words fetched per grid step beyond its own rows
+                      (halo rows re-read by windowed stencil kernels)
     min_block       — hard floor (e.g. 2*halo for stencil kernels)
     probe           — block -> thunk; required for measured (TPU) tuning
     n_shards, k_rhs — sharding degree / RHS batch of the caller; part of
@@ -172,7 +175,8 @@ def best_block(kind: str, n: int, dtype, *,
         scored = [(_measure(probe(b)), b) for b in feasible]
     else:
         scored = [(modeled_words(n, b, words_per_row=words_per_row,
-                                 resident_words=resident_words), b)
+                                 resident_words=resident_words,
+                                 step_words=step_words), b)
                   for b in feasible]
     # min score; ties resolved toward the LARGER block (fewer grid steps)
     best = min(scored, key=lambda sb: (sb[0], -sb[1]))[1]

@@ -1,9 +1,10 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in ``interpret=True`` mode — the kernel
+On the CPU backend kernels run in ``interpret=True`` mode — the kernel
 body executes in Python/XLA for correctness validation; on TPU the same
-calls lower to Mosaic.  Wrappers pad the row dimension to the block size so
-callers never worry about alignment.
+calls lower to Mosaic.  Any other backend raises: there is no silent
+interpreted fallback off the CPU.  Wrappers pad the row dimension to the
+block size so callers never worry about alignment.
 """
 from __future__ import annotations
 
@@ -19,10 +20,19 @@ from repro.kernels import pipecg_fused as _pf
 from repro.kernels import pipecg_spmv_fused as _ps
 from repro.kernels import spmv_dia as _sd
 from repro.kernels import ref
+from repro.kernels import stencil
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode on the CPU backend, Mosaic on a TPU; nothing else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels lower to Mosaic on a TPU and interpret on the "
+        f"CPU; backend {backend!r} has neither")
 
 
 def _rel_words(dtype, ref_dtype) -> float:
@@ -54,13 +64,19 @@ def _pad_to(x, mult, axis=0):
 @functools.partial(jax.jit, static_argnums=(0, 3))
 def spmv_dia_ext(offsets: Tuple[int, ...], bands, x_ext, halo: int):
     """Banded SpMV on a halo-extended vector (kernel-backed)."""
-    block = min(_sd.DEFAULT_BLOCK, bands.shape[1])
-    if bands.shape[1] % block:
-        bands_p, n = _pad_to(bands, block, axis=1)
-        xp = jnp.pad(x_ext, (0, bands_p.shape[1] - n))
-        y = _sd.spmv_dia(offsets, bands_p, xp, halo, block=block,
-                         interpret=_interpret())
-        return y[:n]
+    from repro.kernels import autotune
+
+    nb, n = bands.shape
+    hb = _sd.halo_rows(offsets, bands.dtype, x_ext.dtype)
+    ro = _rel_words(bands.dtype, x_ext.dtype)
+    block = autotune.best_block(
+        "spmv_dia", n, x_ext.dtype,
+        # tiled words/row: y write + x and band reads; x and the bands
+        # are also read over the 2*hb halo rows of every tile
+        words_per_row=2.0 + nb * ro,
+        step_words=2 * hb * stencil.LANE * (1 + nb * ro),
+        min_block=hb * stencil.LANE,
+        dtype_storage=_storage_key(bands.dtype, x_ext.dtype))
     return _sd.spmv_dia(offsets, bands, x_ext, halo, block=block,
                         interpret=_interpret())
 
@@ -200,51 +216,46 @@ def fused_dots(V, z):
     return _fd.fused_dots(V, z, block=block, interpret=_interpret())
 
 
+def _sweep_block(kind, offsets, bands, x, u, **key):
+    """Autotuned block of the single-sweep PIPECG kernel.
+
+    Per row the sweep reads x, r, c = A^T 1 and writes x, r, u, p; u, p,
+    the bands and diag^-1 are read over each tile plus 2*hb halo rows.
+    r/u/p count at their storage dtype, the operator at its own.
+    """
+    from repro.kernels import autotune
+
+    hb = _ps.halo_rows(offsets, bands.dtype, x.dtype, u.dtype)
+    rs = _rel_words(u.dtype, x.dtype)        # carried r/u/p storage
+    ro = _rel_words(bands.dtype, x.dtype)    # operator storage
+    windowed = 2 * rs + (bands.shape[0] + 1) * ro
+    return autotune.best_block(
+        kind, x.shape[-1], x.dtype,
+        words_per_row=2.0 + 4.0 * rs + ro + windowed,
+        step_words=2 * hb * stencil.LANE * windowed,
+        min_block=hb * stencil.LANE,
+        dtype_storage=_storage_key(u.dtype, x.dtype), **key)
+
+
 @functools.partial(jax.jit, static_argnums=(0,), static_argnames=("block",))
 def pipecg_spmv_fused_step(offsets: Tuple[int, ...], bands, inv_diag,
                            x, r, u, p, alpha, beta, block: int = None):
     """Single-sweep PIPECG iteration (updates + Jacobi + SpMV + dots).
 
     Accepts (n,) vectors with scalar alpha/beta, or batched (k, n) vectors
-    with (k,) alpha/beta.  Pads the row dimension to the block size; the
-    default block comes from the autotuner.
+    with (k,) alpha/beta.  The kernel pads the row dimension to its
+    block; the default block comes from the autotuner.
     """
-    from repro.kernels import autotune
-
     squeeze = x.ndim == 1
     if squeeze:
         x, r, u, p = (v[None] for v in (x, r, u, p))
         alpha = jnp.asarray(alpha)[None]
         beta = jnp.asarray(beta)[None]
-    n = x.shape[1]
-    halo = max(abs(o) for o in offsets)
     if block is None:
-        rs = _rel_words(u.dtype, x.dtype)        # carried r/u/p storage
-        ro = _rel_words(bands.dtype, x.dtype)    # resident operator storage
-        block = autotune.best_block(
-            "pipecg_spmv", n, x.dtype,
-            # tiled words/row: x,r reads + x,r,u,p writes (r/u/p at the
-            # storage dtype, x at accum)
-            words_per_row=2.0 + 4.0 * rs,
-            # once-per-sweep: u, p (+2h), bands (+h), diag^-1 (+h),
-            # ABFT column sums c = A^T 1
-            resident_words=(2 * rs + (bands.shape[0] + 2) * ro) * n,
-            min_block=2 * halo,
-            dtype_storage=_storage_key(u.dtype, x.dtype))
-    block = max(min(block, n), 1)
-    pad = (-n) % block
-    if pad:
-        bands_p, _ = _pad_to(bands, block, axis=1)
-        invd_p = jnp.pad(inv_diag, (0, pad))
-        vecs = [jnp.pad(v, ((0, 0), (0, pad))) for v in (x, r, u, p)]
-        outs = _ps.pipecg_spmv_fused(offsets, bands_p, invd_p, *vecs,
-                                     alpha, beta, block=block,
-                                     interpret=_interpret())
-        outs = tuple(o[:, :n] for o in outs[:4]) + (outs[4],)
-    else:
-        outs = _ps.pipecg_spmv_fused(offsets, bands, inv_diag, x, r, u, p,
-                                     alpha, beta, block=block,
-                                     interpret=_interpret())
+        block = _sweep_block("pipecg_spmv", offsets, bands, x, u)
+    outs = _ps.pipecg_spmv_fused(offsets, bands, inv_diag, x, r, u, p,
+                                 alpha, beta, block=block,
+                                 interpret=_interpret())
     if squeeze:
         outs = tuple(o[0] for o in outs)
     return outs
@@ -261,12 +272,11 @@ def pipecg_spmv_halo_step(offsets: Tuple[int, ...], bands_ext, invd_ext,
     are the (k, 2*halo) ppermute payloads; ``bands_ext`` / ``invd_ext``
     the once-per-solve halo-extended operator.  Returns (x', r', u', p',
     red) where ``red`` (k, 6) is this shard's PARTIAL reduction row
-    including the ABFT checksum entry red[:, 5] (the caller psums it).  The default block is autotuned on
-    (backend, n_local, n_shards, k_rhs) — repeated campaign runs reuse the
-    on-disk cache (kernels/autotune.py).
+    including the ABFT checksum entry red[:, 5] (the caller psums it).
+    The default block is autotuned on (backend, n_local, n_shards,
+    k_rhs) — repeated campaign runs reuse the on-disk cache
+    (kernels/autotune.py).
     """
-    from repro.kernels import autotune
-
     k_rhs, n = x.shape
     halo = max(abs(o) for o in offsets)
     if n < 2 * halo:
@@ -274,15 +284,8 @@ def pipecg_spmv_halo_step(offsets: Tuple[int, ...], bands_ext, invd_ext,
             f"local shard of {n} rows is narrower than the 2*halo={2*halo} "
             "stencil reach; use fewer shards or a wider local block")
     if block is None:
-        rs = _rel_words(u.dtype, x.dtype)
-        ro = _rel_words(bands_ext.dtype, x.dtype)
-        block = autotune.best_block(
-            "pipecg_spmv_halo", n, x.dtype,
-            words_per_row=2.0 + 4.0 * rs,
-            resident_words=(2 * rs + (bands_ext.shape[0] + 2) * ro) * n,
-            min_block=2 * halo, n_shards=n_shards, k_rhs=k_rhs,
-            dtype_storage=_storage_key(u.dtype, x.dtype))
-    block = max(min(block, n), 2 * halo)
+        block = _sweep_block("pipecg_spmv_halo", offsets, bands_ext, x, u,
+                             n_shards=n_shards, k_rhs=k_rhs)
     return _ps.pipecg_spmv_halo(offsets, bands_ext, invd_ext, x, r, u, p,
                                 (u_left, u_right), (p_left, p_right),
                                 alpha, beta, block=block,
@@ -459,7 +462,7 @@ def pipebicgstab_halo_step(offsets: Tuple[int, ...], bands_ext, x, r, w, t,
 @jax.jit
 def pipecg_fused_step(x, r, u, w, m, n_, z, q, s, p, alpha, beta):
     """Fused PIPECG updates + dots (update-kernel path, padded)."""
-    block = min(_pf.DEFAULT_BLOCK, x.shape[0])
+    block = _pf.DEFAULT_BLOCK
     if x.shape[0] % block:
         vecs = [x, r, u, w, m, n_, z, q, s, p]
         padded = []

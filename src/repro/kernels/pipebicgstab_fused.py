@@ -79,17 +79,15 @@ def _kernel(sc_ref, bands_ref, csum_ref, w_ref, t_ref, c_ref, x_ref,
     omega = sc_ref[2]
 
     # resident operands are extended by 2h per side: index 0 == row -2h
-    w2 = pl.load(w_ref, (pl.dslice(base, block + 4 * h),)).astype(acc)
-    t2 = pl.load(t_ref, (pl.dslice(base, block + 4 * h),)).astype(acc)
-    c2 = pl.load(c_ref, (pl.dslice(base, block + 4 * h),)).astype(acc)
+    w2 = w_ref[pl.ds(base, block + 4 * h)].astype(acc)
+    t2 = t_ref[pl.ds(base, block + 4 * h)].astype(acc)
+    c2 = c_ref[pl.ds(base, block + 4 * h)].astype(acc)
     z2 = t2 + beta * c2                      # z on rows [base-2h, ..+2h)
 
     # v = A z on rows [base-h, base+block+h); bands_ref index 0 == row -h
     v1 = jnp.zeros((block + 2 * h,), acc)
     for k, off in enumerate(offsets):        # static unroll over bands
-        bk = pl.load(bands_ref,
-                     (pl.dslice(k, 1),
-                      pl.dslice(base, block + 2 * h)))[0].astype(acc)
+        bk = bands_ref[k, pl.ds(base, block + 2 * h)].astype(acc)
         v1 = v1 + bk * jax.lax.dynamic_slice_in_dim(
             z2, h + off, block + 2 * h)
 
@@ -102,9 +100,7 @@ def _kernel(sc_ref, bands_ref, csum_ref, w_ref, t_ref, c_ref, x_ref,
     # t' = A w' on the tile rows
     tn = jnp.zeros((block,), acc)
     for k, off in enumerate(offsets):
-        bk = pl.load(bands_ref,
-                     (pl.dslice(k, 1),
-                      pl.dslice(base + h, block)))[0].astype(acc)
+        bk = bands_ref[k, pl.ds(base + h, block)].astype(acc)
         tn = tn + bk * jax.lax.dynamic_slice_in_dim(wn1, h + off, block)
 
     # tile-level updates
@@ -147,7 +143,7 @@ def _kernel(sc_ref, bands_ref, csum_ref, w_ref, t_ref, c_ref, x_ref,
     # residual 1^T(Aw') - c^T w' rides a 7th Gram row through the same
     # (single) psum; |.| is taken after the reduction (C rows are already
     # pad-masked, so tn/wn here are C[2]/C[1]).
-    c_tile = pl.load(csum_ref, (pl.dslice(base, block),)).astype(acc)
+    c_tile = csum_ref[pl.ds(base, block)].astype(acc)
     gram_o[NBASIS, 0] += jnp.sum(C[2]) - jnp.sum(c_tile * C[1])
 
 

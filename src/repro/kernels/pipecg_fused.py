@@ -20,6 +20,10 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import stencil
+from repro.kernels.stencil import LANE
 
 DEFAULT_BLOCK = 1024
 NVEC = 10  # x, r, u, w, m, n, z, q, s, p
@@ -54,10 +58,10 @@ def _pipecg_kernel(ab_ref, x_ref, r_ref, u_ref, w_ref, m_ref, n_ref,
     def _init():
         red_o[...] = jnp.zeros_like(red_o)
 
-    # next iteration's fused reduction partials (gamma', delta', rr')
-    red_o[0] += jnp.sum(r2 * u2)
-    red_o[1] += jnp.sum(w2 * u2)
-    red_o[2] += jnp.sum(r2 * r2)
+    # (1, 128) lane partials per dot; the caller sums the lanes
+    lanes = lambda v: jnp.sum(v, axis=0, keepdims=True)
+    red_o[...] += stencil.partials_tile(
+        [lanes(r2 * u2), lanes(w2 * u2), lanes(r2 * r2)], red_o.dtype)
 
 
 def pipecg_fused(x, r, u, w, m, n_, z, q, s, p, alpha, beta, *,
@@ -68,21 +72,26 @@ def pipecg_fused(x, r, u, w, m, n_, z, q, s, p, alpha, beta, *,
     Returns (x', r', u', w', z', q', s', p', red) with ``red`` (3,) =
     (<r',u'>, <w',u'>, <r',r'>); the M-apply and SpMV sweeps stay with
     the caller (the update-kernel fallback path of the FusedEngine).
+    Vectors are tiled lane-dense as (rows, 128); n must be a multiple of
+    ``block`` and ``block`` of 8 * 128 (the ops.py wrapper pads).
     """
     n = x.shape[0]
-    assert n % block == 0, (n, block)
-    grid = (n // block,)
+    assert n % block == 0 and block % (8 * LANE) == 0, (n, block)
+    rows = block // LANE
     dt = x.dtype
     ab = jnp.stack([jnp.asarray(alpha, dt), jnp.asarray(beta, dt)])
 
-    vec_spec = pl.BlockSpec((block,), lambda i: (i,))
+    vec_spec = pl.BlockSpec((rows, LANE), lambda i: (i, 0))
     outs = pl.pallas_call(
         _pipecg_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((2,), lambda i: (0,))] + [vec_spec] * NVEC,
-        out_specs=[vec_spec] * 8 + [pl.BlockSpec((3,), lambda i: (0,))],
-        out_shape=[jax.ShapeDtypeStruct((n,), dt)] * 8
-        + [jax.ShapeDtypeStruct((3,), dt)],
+        grid=(n // block,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [vec_spec] * NVEC,
+        out_specs=[vec_spec] * 8
+        + [pl.BlockSpec((stencil.RED_ROWS, LANE), lambda i: (0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((n // LANE, LANE), dt)] * 8
+        + [jax.ShapeDtypeStruct((stencil.RED_ROWS, LANE), dt)],
         interpret=interpret,
-    )(ab, x, r, u, w, m, n_, z, q, s, p)
-    return tuple(outs)
+    )(ab, *(v.reshape(n // LANE, LANE) for v in (x, r, u, w, m, n_, z, q,
+                                                  s, p)))
+    return tuple(o.reshape(n) for o in outs[:8]) + (
+        jnp.sum(outs[8][:3], axis=-1),)

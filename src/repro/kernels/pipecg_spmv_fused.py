@@ -21,27 +21,22 @@ else is re-derived inside the tile sweep:
               1^T w' - c^T u'   (ABFT checksum of the in-kernel SpMV)
 
 The halo recompute duplicates O(halo) flops per tile — free on a
-memory-bound kernel.  ``u``, ``p``, the bands and ``diag^-1`` ride along
-VMEM-resident with zero halos (the spmv_dia trick), so per iteration the
-kernel moves
+memory-bound kernel.  Every operand is tiled lane-dense as (rows, 128)
+(kernels/stencil.py): ``u``, ``p``, the bands and ``diag^-1`` are read
+as a window of the tile plus ``hb`` rows on each side, so per iteration
+the kernel moves
 
-    reads:  x, r (tiled) + u, p, diag^-1, c = A^T 1 (resident)
-            + bands (resident)
+    reads:  x, r, c = A^T 1 (tile) + u, p, diag^-1, bands (window)
     writes: x', r', u', p'
     ==  (10 + n_bands) n words  ==  13n for the tridiagonal ex23 operator
+    plus the window overlap, (4 + n_bands) * 2 hb * 128 words per tile
     (the +1n over PR 5's 12n is the ABFT column-sum vector; the checksum
     residual itself rides the existing reduction row for free)
 
 vs ~38n for the unfused chain (8 AXPYs x 3 + 3 dots x 2 + M-apply x 3 +
 SpMV x 5).  A leading multi-RHS grid dimension batches k right-hand sides
-over the same resident operator, amortizing the band + diag reads.
-
-Caveat on the 12n figure: it is the traffic of the pallas_call itself.
-The host-side wrapper zero-extends u and p by 2h with ``jnp.pad`` each
-call — an XLA copy (~4n extra words) that a production path would avoid
-by carrying halo-extended state between iterations; it is kept here
-because the padded layout would leak into every engine-state consumer
-for a constant-factor win the interpret-mode benchmarks cannot observe.
+over the same operator.  The rows just outside the vector come from a
+small edge operand (zeros here), so no padded copy of u or p is made.
 
 The reduction partials feed BOTH inner-product modes: CG-style (ip='id':
 gamma=<r,u>, delta=<w,u>) and CR-style (ip='A': gamma=<r,w>, delta=<w,w>).
@@ -58,11 +53,11 @@ words for the tridiagonal operator (vs 13n) — measured and gated by the
 ``pipecg_spmv_fused_bf16`` row of BENCH_kernels.json.
 
 ``pipecg_spmv_halo`` is the sharded rendering of the same sweep: instead
-of zero halo extensions, the caller passes the 2h left/right rows of u/p
+of zero edge rows, the caller passes the 2h left/right rows of u/p
 received from its ring neighbors (``lax.ppermute`` inside shard_map) and
 an operator (bands, diag^-1) pre-extended by h with the neighbors' rows —
 loop-invariant, exchanged once per solve.  The kernel body is identical;
-only the provenance of the extension rows differs, so one local iteration
+only the provenance of the edge rows differs, so one local iteration
 (updates + Jacobi + DIA SpMV + partial dots) still costs one HBM pass per
 shard, and the emitted reduction row is a PARTIAL sum the distributed
 driver finishes with a deferred psum (split-phase, see
@@ -78,70 +73,55 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import stencil
 from repro.kernels.checksum import dia_column_checksum
+from repro.kernels.stencil import LANE
 
 DEFAULT_BLOCK = 1024
 NRED = 6  # <r,u>, <w,u>, <r,r>, <r,w>, <w,w>, ABFT 1^T(Au') - c^T u'
 
 
-def _kernel(ab_ref, bands_ref, invd_ref, csum_ref, u_ref, p_ref, x_ref,
-            r_ref, xo, ro, uo, po, red_o, *, offsets: Sequence[int],
-            halo: int, block: int, n_valid: int = None):
+def _kernel(ab_ref, *refs, offsets: Sequence[int], hb: int,
+            n_valid: int = None):
+    (b_c, b_l, b_r, b_e, d_c, d_l, d_r, d_e, u_c, u_l, u_r, u_e,
+     p_c, p_l, p_r, p_e, csum_ref, x_ref, r_ref,
+     xo, ro, uo, po, red_o) = refs
     j = pl.program_id(0)          # RHS index (batch)
     i = pl.program_id(1)          # tile index
-    base = i * block
-    h = halo
+    rows = x_ref.shape[1]
     # accumulation dtype: every load is up-cast here and all arithmetic,
     # reduction partials and x ride at this precision; only the r/u/p
     # stores down-cast back to the carried storage dtype (bf16/fp8 under
     # a PrecisionPolicy, == acc on the default fp32/fp64 path)
     acc = red_o.dtype
-    alpha = ab_ref[0, 0]
-    beta = ab_ref[0, 1]
+    alpha = ab_ref[j, 0].astype(acc)
+    beta = ab_ref[j, 1].astype(acc)
+    win = lambda refs4, k: stencil.window(refs4, k, hb, acc)
+    mid = lambda v: v[hb:hb + rows]   # the tile's own rows of a window
 
-    # stage 1: p' = u + beta p on rows [base-2h, base+block+2h)
-    #   (u_ref / p_ref are zero-extended by 2h, so index 0 == row -2h)
-    u_2h = pl.load(u_ref, (pl.dslice(0, 1),
-                           pl.dslice(base, block + 4 * h)))[0].astype(acc)
-    p_2h = pl.load(p_ref, (pl.dslice(0, 1),
-                           pl.dslice(base, block + 4 * h)))[0].astype(acc)
-    p2_2h = u_2h + beta * p_2h
-
-    # stage 2: s' = A p' and q' = diag^-1 s' on rows [base-h, base+block+h)
-    #   (bands_ref / invd_ref are zero-extended by h, index 0 == row -h)
-    s2_h = jnp.zeros((block + 2 * h,), acc)
+    # every stage runs on the window [tile - hb rows, tile + hb rows);
+    # each stencil leaves ceil(h/128) garbage rows at both ends, which
+    # the 2-stencil chain keeps inside the hb halo rows
+    u_w = win((u_c, u_l, u_r, u_e), 0)
+    p2 = u_w + beta * win((p_c, p_l, p_r, p_e), 0)        # p' = u + beta p
+    s2 = jnp.zeros_like(p2)
     for k, off in enumerate(offsets):  # static unroll over bands
-        bk = pl.load(bands_ref,
-                     (pl.dslice(k, 1),
-                      pl.dslice(base, block + 2 * h)))[0].astype(acc)
-        s2_h = s2_h + bk * jax.lax.dynamic_slice_in_dim(
-            p2_2h, h + off, block + 2 * h)
-    invd_h = pl.load(invd_ref, (pl.dslice(base, block + 2 * h),)).astype(acc)
-    q2_h = invd_h * s2_h
-
-    # stage 3: u' = u - alpha q' on rows [base-h, base+block+h)
-    u2_h = jax.lax.dynamic_slice_in_dim(u_2h, h, block + 2 * h) - alpha * q2_h
-
-    # stage 4: w' = A u' on the tile rows [base, base+block)
-    w2 = jnp.zeros((block,), acc)
+        s2 = s2 + win((b_c, b_l, b_r, b_e), k) * stencil.shift(p2, off)
+    q2 = win((d_c, d_l, d_r, d_e), 0) * s2                # q' = diag^-1 s'
+    u2 = u_w - alpha * q2                                 # u' = u - alpha q'
+    w2 = jnp.zeros((rows, LANE), acc)                     # w' = A u' (tile)
     for k, off in enumerate(offsets):
-        bk = pl.load(bands_ref,
-                     (pl.dslice(k, 1),
-                      pl.dslice(base + h, block)))[0].astype(acc)
-        w2 = w2 + bk * jax.lax.dynamic_slice_in_dim(u2_h, h + off, block)
+        w2 = w2 + b_c[k].astype(acc) * mid(stencil.shift(u2, off))
 
-    # tile-level updates
-    p2 = jax.lax.dynamic_slice_in_dim(p2_2h, 2 * h, block)
-    s2 = jax.lax.dynamic_slice_in_dim(s2_h, h, block)
-    u2 = jax.lax.dynamic_slice_in_dim(u2_h, h, block)
-    x2 = x_ref[0, :].astype(acc) + alpha * p2
-    r2 = r_ref[0, :].astype(acc) - alpha * s2
-
-    xo[0, :] = x2.astype(xo.dtype)
-    ro[0, :] = r2.astype(ro.dtype)
-    uo[0, :] = u2.astype(uo.dtype)
-    po[0, :] = p2.astype(po.dtype)
+    p2, s2, u2 = mid(p2), mid(s2), mid(u2)
+    x2 = x_ref[0].astype(acc) + alpha * p2
+    r2 = r_ref[0].astype(acc) - alpha * s2
+    xo[0] = x2.astype(xo.dtype)
+    ro[0] = r2.astype(ro.dtype)
+    uo[0] = u2.astype(uo.dtype)
+    po[0] = p2.astype(po.dtype)
 
     @pl.when(i == 0)
     def _init():
@@ -150,20 +130,19 @@ def _kernel(ab_ref, bands_ref, invd_ref, csum_ref, u_ref, p_ref, x_ref,
     # next iteration's fused reduction partials; rows >= n_valid are pad
     # rows whose values may carry halo (neighbor) data — mask them out
     if n_valid is not None:
-        rows = base + jax.lax.broadcasted_iota(jnp.int32, (block,), 0)
-        keep = rows < n_valid
+        idx = ((i * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, LANE), 0))
+               * LANE + jax.lax.broadcasted_iota(jnp.int32, (rows, LANE), 1))
+        keep = idx < n_valid
         r2, u2, w2 = (jnp.where(keep, v, 0) for v in (r2, u2, w2))
-    red_o[0, 0] += jnp.sum(r2 * u2)
-    red_o[0, 1] += jnp.sum(w2 * u2)
-    red_o[0, 2] += jnp.sum(r2 * r2)
-    red_o[0, 3] += jnp.sum(r2 * w2)
-    red_o[0, 4] += jnp.sum(w2 * w2)
     # ABFT checksum partial for the in-kernel SpMV w' = A u': the signed
     # residual 1^T(Au') - c^T u' with c = A^T 1 (kernels/checksum.py).
     # Rounding-level when the sweep executed faithfully, O(corruption)
     # otherwise; the consumer takes |.| after finishing the psum.
-    c_tile = pl.load(csum_ref, (pl.dslice(base, block),)).astype(acc)
-    red_o[0, 5] += jnp.sum(w2) - jnp.sum(c_tile * u2)
+    c_t = csum_ref[0].astype(acc)
+    lanes = lambda v: jnp.sum(v, axis=0, keepdims=True)
+    red_o[0] += stencil.partials_tile(
+        [lanes(r2 * u2), lanes(w2 * u2), lanes(r2 * r2), lanes(r2 * w2),
+         lanes(w2 * w2), lanes(w2 - c_t * u2)], acc)
 
 
 def _ab(alpha, beta, k_rhs, dt):
@@ -172,52 +151,64 @@ def _ab(alpha, beta, k_rhs, dt):
     return ab.reshape(k_rhs, 2)
 
 
-def _sweep(offsets, bands_e, invd_e, csum, u_e, p_e, x, r, ab, *, halo: int,
-           block: int, n_valid: int = None, interpret: bool = False
-           ) -> Tuple[jnp.ndarray, ...]:
-    """The shared pallas_call: one grid sweep over pre-extended operands.
+def halo_rows(offsets: Sequence[int], *dtypes) -> int:
+    """Window rows per side of the sweep for this operator and dtypes.
 
-    ``bands_e`` / ``invd_e`` are extended by ``halo`` rows each side and
-    ``u_e`` / ``p_e`` by ``2*halo`` — with zeros (single-device path) or
-    neighbor rows (sharded path).  ``csum`` (n,) holds the local slice of
-    the ABFT column sums c = A^T 1 (resident, loop-invariant).
-    ``n_valid`` (static) masks pad rows out of the reduction partials;
-    None means every row is valid.
+    The chain p' -> s' = A p' -> w' = A u' applies the stencil twice.
+    """
+    halo = max(abs(o) for o in offsets)
+    return stencil.halo_rows(halo, 2, stencil.sublanes(*dtypes))
+
+
+def _sweep(offsets, bands, invd, csum, u, p, x, r, ab, edges, *, block: int,
+           n_valid: int = None, interpret: bool = False
+           ) -> Tuple[jnp.ndarray, ...]:
+    """The shared pallas_call: one grid sweep over (k, n) vectors.
+
+    ``bands`` (n_bands, n), ``invd`` / ``csum`` (n,), ``u`` / ``p`` /
+    ``x`` / ``r`` (k, n).  ``edges`` = (bands, invd, u, p) edge operands
+    (stencil.edges): the rows just outside the vector, zeros on one
+    device or the neighbors' rows on a shard.  ``n_valid`` (static)
+    masks pad rows out of the reduction partials; None means every row
+    is valid.
     """
     k_rhs, n = x.shape
-    assert n % block == 0, (n, block)
-    assert block >= 2 * halo, (block, halo)
-    grid = (k_rhs, n // block)
+    nb = bands.shape[0]
+    hb = edges[2].shape[1] // 2
+    assert n % block == 0 and block % (hb * LANE) == 0, (n, block, hb)
+    rows, n_rows = block // LANE, n // LANE
     # x and the reduction row stay at the solve (accumulation) dtype;
     # r/u/p keep whatever storage dtype the caller carries them in
     dt = x.dtype
 
-    kern = functools.partial(_kernel, offsets=tuple(offsets), halo=halo,
-                             block=block, n_valid=n_valid)
-    vec_spec = pl.BlockSpec((1, block), lambda j, i: (j, i))
-    resident = lambda shape: pl.BlockSpec(shape, lambda j, i: (0,) * len(shape))
+    kern = functools.partial(_kernel, offsets=tuple(offsets), hb=hb,
+                             n_valid=n_valid)
+    win = functools.partial(stencil.window_specs, rows=rows, hb=hb,
+                            n_rows=n_rows)
+    tile = lambda lead, batched: pl.BlockSpec(
+        (lead, rows, LANE),
+        (lambda j, i: (j, i, 0)) if batched else (lambda j, i: (0, i, 0)))
+    rowsv = stencil.as_rows
     outs = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda j, i: (j, 0)),          # alpha/beta
-            resident(bands_e.shape),                            # bands (+h)
-            resident(invd_e.shape),                             # diag^-1 (+h)
-            resident(csum.shape),                               # c = A^T 1
-            pl.BlockSpec((1, n + 4 * halo), lambda j, i: (j, 0)),  # u (+2h)
-            pl.BlockSpec((1, n + 4 * halo), lambda j, i: (j, 0)),  # p (+2h)
-            vec_spec,                                           # x
-            vec_spec,                                           # r
-        ],
-        out_specs=[vec_spec] * 4 + [pl.BlockSpec((1, NRED), lambda j, i: (j, 0))],
-        out_shape=[jax.ShapeDtypeStruct((k_rhs, n), dt),
-                   jax.ShapeDtypeStruct((k_rhs, n), r.dtype),
-                   jax.ShapeDtypeStruct((k_rhs, n), u_e.dtype),
-                   jax.ShapeDtypeStruct((k_rhs, n), p_e.dtype),
-                   jax.ShapeDtypeStruct((k_rhs, NRED), dt)],
+        grid=(k_rhs, n // block),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]       # alpha/beta
+        + win(nb, batched=False) + win(1, batched=False)       # bands, diag^-1
+        + win(1, batched=True) + win(1, batched=True)          # u, p
+        + [tile(1, False), tile(1, True), tile(1, True)],      # c, x, r
+        out_specs=[tile(1, True)] * 4
+        + [pl.BlockSpec((1, stencil.RED_ROWS, LANE), lambda j, i: (j, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((k_rhs, n_rows, LANE), dt),
+                   jax.ShapeDtypeStruct((k_rhs, n_rows, LANE), r.dtype),
+                   jax.ShapeDtypeStruct((k_rhs, n_rows, LANE), u.dtype),
+                   jax.ShapeDtypeStruct((k_rhs, n_rows, LANE), p.dtype),
+                   jax.ShapeDtypeStruct((k_rhs, stencil.RED_ROWS, LANE), dt)],
         interpret=interpret,
-    )(ab, bands_e, invd_e, csum, u_e, p_e, x, r)
-    return tuple(outs)
+    )(ab, *[rowsv(bands)] * 3, edges[0], *[rowsv(invd)] * 3, edges[1],
+      *[rowsv(u)] * 3, edges[2], *[rowsv(p)] * 3, edges[3],
+      rowsv(csum), rowsv(x), rowsv(r))
+    vecs = tuple(o.reshape(k_rhs, n) for o in outs[:4])
+    return vecs + (jnp.sum(outs[4][:, :NRED], axis=-1),)
 
 
 def pipecg_spmv_fused(offsets: Sequence[int], bands: jnp.ndarray,
@@ -229,7 +220,9 @@ def pipecg_spmv_fused(offsets: Sequence[int], bands: jnp.ndarray,
     All vectors are (k, n) — k right-hand sides batched over the leading
     grid dimension; ``alpha`` / ``beta`` are (k,).  ``bands`` is
     (n_bands, n), ``inv_diag`` (n,); both are shared across the batch.
-    n must be a multiple of ``block`` (the ops.py wrapper pads).
+    ``block`` is rounded up to whole halo windows (``halo_rows(...) *
+    128`` rows) and n is zero-padded to it; zero rows stay zero and add
+    nothing to the partials.
 
     Returns (x', r', u', p', red) with red (k, 6) =
     (<r',u'>, <w',u'>, <r',r'>, <r',w'>, <w',w'>, chk) per RHS, where
@@ -237,16 +230,23 @@ def pipecg_spmv_fused(offsets: Sequence[int], bands: jnp.ndarray,
     in-kernel SpMV (rounding-level unless the sweep was corrupted).
     """
     k_rhs, n = x.shape
-    halo = max(abs(o) for o in offsets)
-    # zero halo extensions (resident operands; fetched once, revisited)
-    bands_e = jnp.pad(bands, ((0, 0), (halo, halo)))
-    invd_e = jnp.pad(inv_diag, (halo, halo))
+    hb = halo_rows(offsets, bands.dtype, inv_diag.dtype, x.dtype, r.dtype,
+                   u.dtype, p.dtype)
+    block = stencil.legal_block(min(block, n), hb)
     csum = dia_column_checksum(offsets, bands)
-    u_e = jnp.pad(u, ((0, 0), (2 * halo, 2 * halo)))
-    p_e = jnp.pad(p, ((0, 0), (2 * halo, 2 * halo)))
-    return _sweep(offsets, bands_e, invd_e, csum, u_e, p_e, x, r,
-                  _ab(alpha, beta, k_rhs, x.dtype), halo=halo, block=block,
+    pad = (-n) % block
+    if pad:
+        padded = lambda v: jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, pad)])
+        bands, inv_diag, csum, x, r, u, p = map(
+            padded, (bands, inv_diag, csum, x, r, u, p))
+    edges = (stencil.zero_edges(bands.shape[0], hb, bands.dtype),
+             stencil.zero_edges(1, hb, inv_diag.dtype),
+             stencil.zero_edges(k_rhs, hb, u.dtype),
+             stencil.zero_edges(k_rhs, hb, p.dtype))
+    outs = _sweep(offsets, bands, inv_diag, csum, u, p, x, r,
+                  _ab(alpha, beta, k_rhs, x.dtype), edges, block=block,
                   interpret=interpret)
+    return tuple(o[:, :n] for o in outs[:4]) + (outs[4],)
 
 
 def pipecg_spmv_halo(offsets: Sequence[int], bands_ext: jnp.ndarray,
@@ -257,7 +257,7 @@ def pipecg_spmv_halo(offsets: Sequence[int], bands_ext: jnp.ndarray,
                      ) -> Tuple[jnp.ndarray, ...]:
     """Sharded single-sweep PIPECG iteration with neighbor-supplied halos.
 
-    Same sweep as :func:`pipecg_spmv_fused`, but the extension rows are
+    Same sweep as :func:`pipecg_spmv_fused`, but the edge rows are
     real neighbor data instead of zeros:
 
     * ``u_lr`` / ``p_lr``: ``(left, right)`` halo rows of width ``2*halo``
@@ -268,11 +268,13 @@ def pipecg_spmv_halo(offsets: Sequence[int], bands_ext: jnp.ndarray,
       operator rows pre-extended by ``halo`` per side with the neighbors'
       values — loop-invariant, exchanged once per solve.
 
-    Pads the row dimension to ``block`` internally; pad rows are masked
-    out of the reduction partials (they see halo data, not zeros).  The
-    returned ``red`` (k, 6) holds this shard's PARTIAL sums — the caller
-    must finish them with a ``psum`` over the mesh axis.  That includes
-    the checksum entry red[:, 5]: the column sums are computed from
+    ``block`` is rounded up to whole halo windows.  When n is not a
+    multiple of it the rows are padded, the right halo rows
+    sit right after row n-1, and pad rows are masked out of the
+    reduction partials (they see halo data, not zeros).  The returned
+    ``red`` (k, 6) holds this shard's PARTIAL sums — the caller must
+    finish them with a ``psum`` over the mesh axis.  That includes the
+    checksum entry red[:, 5]: the column sums are computed from
     ``bands_ext`` (halo=h), i.e. the local slice of the GLOBAL c = A^T 1
     including neighbor-row contributions, so the psum of the per-shard
     row/column partials reproduces the exact global checksum residual
@@ -280,30 +282,42 @@ def pipecg_spmv_halo(offsets: Sequence[int], bands_ext: jnp.ndarray,
     """
     k_rhs, n = x.shape
     halo = max(abs(o) for o in offsets)
-    pad = (-n) % block
     u_l, u_r = u_lr
     p_l, p_r = p_lr
     assert u_l.shape == (k_rhs, 2 * halo), (u_l.shape, k_rhs, halo)
-    # extension layout: [left halo | local rows | right halo | zero pad] —
-    # the pad must come AFTER the right halo so row n-1's stencil still
-    # reads the neighbor rows at n..n+2h-1 (pads match each carried
-    # array's storage dtype so a bf16 policy stays bf16 end to end)
-    zpad_u = jnp.zeros((k_rhs, pad), u.dtype)
-    zpad_p = jnp.zeros((k_rhs, pad), p.dtype)
-    u_e = jnp.concatenate([u_l.astype(u.dtype), u, u_r.astype(u.dtype),
-                           zpad_u], axis=-1)
-    p_e = jnp.concatenate([p_l.astype(p.dtype), p, p_r.astype(p.dtype),
-                           zpad_p], axis=-1)
-    bands_p = jnp.pad(bands_ext, ((0, 0), (0, pad)))
-    invd_p = jnp.pad(invd_ext, (0, pad))
-    csum = jnp.pad(dia_column_checksum(offsets, bands_ext, halo=halo),
-                   (0, pad))
-    x_p = jnp.pad(x, ((0, 0), (0, pad)))
-    r_p = jnp.pad(r, ((0, 0), (0, pad)))
-    outs = _sweep(offsets, bands_p, invd_p, csum, u_e, p_e, x_p, r_p,
-                  _ab(alpha, beta, k_rhs, x.dtype), halo=halo, block=block,
-                  n_valid=(n if pad else None), interpret=interpret)
-    if pad:
+    hb = halo_rows(offsets, bands_ext.dtype, invd_ext.dtype, x.dtype,
+                   r.dtype, u.dtype, p.dtype)
+    block = stencil.legal_block(min(block, n), hb)
+    csum = dia_column_checksum(offsets, bands_ext, halo=halo)
+    bands_lr = (bands_ext[:, :halo], bands_ext[:, n + halo:])
+    invd_lr = (invd_ext[None, :halo], invd_ext[None, n + halo:])
+    bands, invd = bands_ext[:, halo:n + halo], invd_ext[halo:n + halo]
+    # pads match each carried array's storage dtype so a bf16 policy
+    # stays bf16 end to end
+    u_l, u_r, p_l, p_r = (v.astype(w.dtype)
+                          for v, w in ((u_l, u), (u_r, u), (p_l, p), (p_r, p)))
+    n_valid = None
+    if n % block:
+        # [local rows | right halo | zero pad]: row n-1's stencil reads
+        # the neighbor rows at n..n+2h-1, and the edge operand is zero
+        n_valid = n
+        n_pad = -(-(n + 2 * halo) // block) * block
+        ext = lambda v, right: jnp.concatenate(
+            [v, right, jnp.zeros(v.shape[:-1] + (n_pad - n - right.shape[-1],),
+                                 v.dtype)], axis=-1)
+        bands, invd = ext(bands, bands_lr[1]), ext(invd[None], invd_lr[1])[0]
+        u, p = ext(u, u_r), ext(p, p_r)
+        csum, x, r = (jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, n_pad - n)])
+                      for v in (csum, x, r))
+        bands_lr = (bands_lr[0], bands_lr[1][:, :0])
+        invd_lr = (invd_lr[0], invd_lr[1][:, :0])
+        u_r, p_r = u_r[:, :0], p_r[:, :0]
+    edges = (stencil.edges(*bands_lr, hb), stencil.edges(*invd_lr, hb),
+             stencil.edges(u_l, u_r, hb), stencil.edges(p_l, p_r, hb))
+    outs = _sweep(offsets, bands, invd, csum, u, p, x, r,
+                  _ab(alpha, beta, k_rhs, x.dtype), edges, block=block,
+                  n_valid=n_valid, interpret=interpret)
+    if n_valid is not None:
         outs = tuple(o[:, :n] for o in outs[:4]) + (outs[4],)
     return outs
 
@@ -356,14 +370,13 @@ def _chain_kernel(th_ref, bands_ref, p_ref, r_ref, chain_o, gram_o, *,
     def links(ref, depth):
         # a_j[q] = (Ã^j v)[base - (H - j*h) + q]; refs are +H extended so
         # index 0 == global row -H and global row g sits at index g + H
-        a = pl.load(ref, (pl.dslice(base, block + 2 * H),)).astype(acc)
+        a = ref[pl.ds(base, block + 2 * H)].astype(acc)
         out = [jax.lax.dynamic_slice_in_dim(a, H, block)]
         for j in range(1, depth + 1):
             nxt = jnp.zeros((block + 2 * (H - j * halo),), acc)
-            bk_rows = pl.dslice(base + j * halo, block + 2 * (H - j * halo))
+            bk_rows = pl.ds(base + j * halo, block + 2 * (H - j * halo))
             for k, off in enumerate(offsets):
-                bk = pl.load(bands_ref,
-                             (pl.dslice(k, 1), bk_rows))[0].astype(acc)
+                bk = bands_ref[k, bk_rows].astype(acc)
                 nxt = nxt + bk * jax.lax.dynamic_slice_in_dim(
                     a, halo + off, block + 2 * (H - j * halo))
             a = nxt * th_inv

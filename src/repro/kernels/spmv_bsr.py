@@ -40,9 +40,8 @@ NRED = 6  # <r,u>, <w,u>, <r,r>, <r,w>, <w,w>, ABFT 1^T(Au') - c^T u'
 def _spmv_kernel(idx_ref, blocks_ref, xb_ref, yo, *, brows: int):
     i = pl.program_id(0)
     base = i * brows
-    idx = pl.load(idx_ref, (pl.dslice(base, brows), slice(None)))
-    blk = pl.load(blocks_ref, (pl.dslice(base, brows), slice(None),
-                               slice(None), slice(None)))
+    idx = idx_ref[pl.ds(base, brows), :]
+    blk = blocks_ref[pl.ds(base, brows), :, :, :]
     xb = xb_ref[...]                      # resident (nbr, bs)
     g = jnp.take(xb, idx, axis=0)         # (brows, deg, bs)
     yo[...] = jnp.einsum("rdij,rdj->ri", blk, g).astype(yo.dtype)
@@ -91,10 +90,8 @@ def _fused_kernel(ab_ref, idx_ref, blocks_ref, invd_ref, csum_ref, u_ref,
     # the RHS block is already selected by the BlockSpec index map; load
     # leading index 0 within the block (j only names the grid position)
     del j
-    u_all = pl.load(u_ref, (pl.dslice(0, 1), slice(None),
-                            slice(None)))[0].astype(acc)   # (nbr, bs)
-    p_all = pl.load(p_ref, (pl.dslice(0, 1), slice(None),
-                            slice(None)))[0].astype(acc)
+    u_all = u_ref[0].astype(acc)                     # (nbr, bs)
+    p_all = p_ref[0].astype(acc)
     # stage 1 everywhere: p' = u + beta p (vector-sized, VMEM-resident)
     pp_all = u_all + beta * p_all
 
@@ -138,8 +135,7 @@ def _fused_kernel(ab_ref, idx_ref, blocks_ref, invd_ref, csum_ref, u_ref,
     red_o[0, 2] += jnp.sum(r2 * r2)
     red_o[0, 3] += jnp.sum(r2 * w2)
     red_o[0, 4] += jnp.sum(w2 * w2)
-    c_t = pl.load(csum_ref, (pl.dslice(base, brows),
-                             slice(None))).astype(acc)
+    c_t = csum_ref[pl.ds(base, brows), :].astype(acc)
     red_o[0, 5] += jnp.sum(w2) - jnp.sum(c_t * u2)
 
 

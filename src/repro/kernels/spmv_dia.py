@@ -1,11 +1,11 @@
 """Pallas TPU kernel: banded (DIA) SpMV — the paper's compute hot-spot.
 
-TPU adaptation of the stencil SpMV (DESIGN.md §Hardware-adaptation): rows
-are tiled into VMEM blocks sized for the VPU (8x128 lanes); the halo-extended
-input vector stays VMEM-resident (per-chip shards of the paper's problems
-are tiny: ex23 at P=8192 is 256 rows/chip; the tiling matters for the
-single-chip benchmark sizes).  Bands and the output are tiled with explicit
-BlockSpecs; the band loop is unrolled at trace time (static offsets).
+TPU adaptation of the stencil SpMV (DESIGN.md §Hardware-adaptation): the
+vector and the bands are viewed lane-dense as ``(rows, 128)`` and tiled
+in whole (8, 128) tiles; each grid step reads its tile of x together
+with ``hb`` halo rows on either side (kernels/stencil.py) and applies
+every band as a static rolled shift of that window.  Nothing is
+resident, so the VMEM footprint is a few tiles whatever n is.
 """
 from __future__ import annotations
 
@@ -16,19 +16,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANE = 128
+from repro.kernels import stencil
+from repro.kernels.stencil import LANE
+
 DEFAULT_BLOCK = 8 * LANE  # one (8, 128) VPU tile per grid step
 
 
-def _spmv_kernel(x_ext_ref, bands_ref, y_ref, *, offsets: Sequence[int],
-                 halo: int, block: int):
-    i = pl.program_id(0)
-    base = i * block
-    acc = jnp.zeros((block,), y_ref.dtype)
+def _spmv_kernel(x_c, x_l, x_r, x_e, bands_ref, y_ref, *,
+                 offsets: Sequence[int], hb: int):
+    acc = y_ref.dtype
+    xw = stencil.window((x_c, x_l, x_r, x_e), 0, hb, acc)
+    rows = y_ref.shape[1]
+    y = jnp.zeros((rows, LANE), acc)
     for k, off in enumerate(offsets):  # static unroll over bands
-        xk = pl.load(x_ext_ref, (pl.dslice(base + halo + off, block),))
-        acc = acc + bands_ref[k, :] * xk
-    y_ref[...] = acc
+        y = y + bands_ref[k].astype(acc) * stencil.shift(xw, off)[hb:hb + rows]
+    y_ref[0] = y
+
+
+def halo_rows(offsets: Sequence[int], *dtypes) -> int:
+    """Window rows per side of the SpMV for this operator and dtypes."""
+    halo = max(abs(o) for o in offsets)
+    return stencil.halo_rows(halo, 1, stencil.sublanes(*dtypes))
 
 
 def spmv_dia(offsets: Sequence[int], bands: jnp.ndarray, x_ext: jnp.ndarray,
@@ -36,25 +44,37 @@ def spmv_dia(offsets: Sequence[int], bands: jnp.ndarray, x_ext: jnp.ndarray,
              interpret: bool = False) -> jnp.ndarray:
     """y[i] = sum_k bands[k,i] * x_ext[i + halo + offsets[k]].
 
-    bands (n_bands, n); x_ext (n + 2*halo,).  n must be a multiple of
-    ``block`` (the ops.py wrapper pads).
+    bands (n_bands, n); x_ext (n + 2*halo,).  ``block`` is rounded up to
+    whole halo windows (``halo_rows(...) * 128`` rows); when n is not a
+    multiple of it the rows are zero-padded, with the right halo kept
+    next to row n-1.
     """
-    n = bands.shape[1]
+    nb, n = bands.shape
     assert x_ext.shape[0] == n + 2 * halo, (x_ext.shape, n, halo)
-    assert n % block == 0, (n, block)
-    grid = (n // block,)
-    kernel = functools.partial(_spmv_kernel, offsets=tuple(offsets),
-                               halo=halo, block=block)
-    return pl.pallas_call(
+    hb = halo_rows(offsets, bands.dtype, x_ext.dtype)
+    block = stencil.legal_block(min(block, n), hb)
+    dt = x_ext.dtype
+    left = x_ext[None, :halo]
+    if n % block:
+        # [x | right halo | zeros]: the pad rows carry zero bands
+        n_pad = -(-(n + halo) // block) * block
+        x = jnp.pad(x_ext[halo:], (0, n_pad - n - halo))
+        bands = jnp.pad(bands, ((0, 0), (0, n_pad - n)))
+        right = x_ext[None, :0]
+    else:
+        n_pad = n
+        x = x_ext[halo:halo + n]
+        right = x_ext[None, n + halo:]
+    rows, n_rows = block // LANE, n_pad // LANE
+    kernel = functools.partial(_spmv_kernel, offsets=tuple(offsets), hb=hb)
+    y = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            # halo-extended x: VMEM-resident, same full block every step
-            pl.BlockSpec(x_ext.shape, lambda i: (0,)),
-            # bands: one (n_bands, block) tile per grid step
-            pl.BlockSpec((bands.shape[0], block), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), x_ext.dtype),
+        grid=(1, n_pad // block),
+        in_specs=stencil.window_specs(1, rows, hb, n_rows, batched=False)
+        + [pl.BlockSpec((nb, rows, LANE), lambda j, i: (0, i, 0))],
+        out_specs=pl.BlockSpec((1, rows, LANE), lambda j, i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, n_rows, LANE), dt),
         interpret=interpret,
-    )(x_ext, bands)
+    )(*[stencil.as_rows(x)] * 3, stencil.edges(left, right, hb),
+      stencil.as_rows(bands))
+    return y.reshape(n_pad)[:n]

@@ -167,7 +167,9 @@ def analyze_collectives(hlo: str) -> Dict[str, Dict]:
             "while_trip_counts": trip}
 
 
-_DEF_RE = re.compile(r"^(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(\([^=]*?\)|[\w\[\]{},\s]+?)\s+([\w\-]+)\(")
+# the result type may carry TPU tiled layouts ("{1,0:T(8,128)S(1)}"), so
+# the opcode is the first token after whitespace that opens a paren
+_DEF_RE = re.compile(r"^(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*?)\s+([a-z][\w\-]*)\(")
 _OPERANDS_RE = re.compile(r"\(((?:%[\w.\-]+(?:,\s*)?)+)\)")
 _CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
 _DIMS_RE = re.compile(r"(\w+)\[([\d,]*)\]")

@@ -26,7 +26,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models.moe import capacity
 
@@ -116,7 +115,7 @@ def moe_ffn_ep(p, cfg, x, dtype, mesh: Mesh):
                     P("model", None, None), P("model", None, None))
     else:
         fn2 = fn
-    out, aux, z = shard_map(
+    out, aux, z = jax.shard_map(
         fn2, mesh=mesh, in_specs=in_specs,
-        out_specs=(P(bspec, None, None), P(), P()), check_rep=False)(*args)
+        out_specs=(P(bspec, None, None), P(), P()), check_vma=False)(*args)
     return out, {"moe_aux": aux, "moe_z": z}

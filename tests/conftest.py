@@ -34,6 +34,9 @@ def run_subprocess_with_retry(script: str, env=None, timeout=None,
     """
     timeout = timeout or SUBPROCESS_TIMEOUT_S
     env = dict(env if env is not None else os.environ)
+    # the scripts force host devices: CPU by design, and never a
+    # contender for an accelerator the test process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     last = None
     for attempt in range(retries + 1):
         try:

@@ -100,3 +100,39 @@ def test_kernel_backed_operator_in_solver(rng):
     got = ops.spmv_dia_ext(A.offsets, A.bands, x_ext, 1)
     want = A.matvec(b)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-12)
+
+
+@pytest.mark.parametrize("off", [-300, -129, -128, -1, 1, 7, 127, 128, 130,
+                                 257])
+def test_stencil_shift_is_a_flat_shift(off):
+    """The rolled window shift equals a flat shift away from the ends."""
+    from jax.experimental import pallas as pl
+    from repro.kernels import stencil
+
+    W = 16
+    x = jnp.arange(W * 128, dtype=jnp.float32).reshape(W, 128)
+
+    def kern(x_ref, o_ref):
+        o_ref[...] = stencil.shift(x_ref[...], off)
+
+    got = pl.pallas_call(kern, out_shape=jax.ShapeDtypeStruct(x.shape,
+                                                              x.dtype),
+                         interpret=True)(x)
+    want = jnp.roll(x.reshape(-1), -off).reshape(W, 128)
+    reach = -(-abs(off) // 128)    # rows within reach of an end wrap
+    np.testing.assert_array_equal(np.asarray(got)[reach:W - reach],
+                                  np.asarray(want)[reach:W - reach])
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops._interpret() is interpret
+
+
+def test_no_interpreted_fallback_off_cpu(monkeypatch):
+    """A backend with no Mosaic lowering raises instead of interpreting."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._interpret()
