@@ -371,15 +371,18 @@ def run(out_dir=None):
     pieces, red_sum = [], 0.0
     for s in range(S):
         lo = s * n_local
-        piece = ops.pipecg_spmv_halo_step(
+        local = [v[:, lo:lo + n_local] for v in xs]
+        blk, op = ops.pipecg_halo_operator(
             offsets, bands_g[:, lo:lo + n_local + 2 * halo],
-            invd_g[lo:lo + n_local + 2 * halo],
-            *(v[:, lo:lo + n_local] for v in xs),
+            invd_g[lo:lo + n_local + 2 * halo], local[0], local[2],
+            n_shards=S)
+        piece = ops.pipecg_spmv_halo_step(
+            offsets, op, *local,
             u_g[:, lo:lo + 2 * halo],
             u_g[:, lo + n_local + 2 * halo:lo + n_local + 4 * halo],
             p_g[:, lo:lo + 2 * halo],
             p_g[:, lo + n_local + 2 * halo:lo + n_local + 4 * halo],
-            al, be, n_shards=S)
+            al, be, block=blk)
         pieces.append(piece[:4])
         red_sum = red_sum + piece[4]
     got_cat = [jnp.concatenate([p_[i] for p_ in pieces], axis=-1)

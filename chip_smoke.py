@@ -8,9 +8,9 @@ Default (one chip), fp32 throughout, at n = 2^20 rows:
    the 1-D Laplacian (PETSc ex23), each checked by the host float64
    normwise backward error of its answer;
 3. serve: a ``SolverServer`` (8 slots, fused engine) answering synthetic
-   requests against the same operator; each converged answer is checked
-   against its own tolerance on the host, and every request whose
-   tolerance lies above the fp32 floor must converge.
+   requests against the same operator; every answer is checked on the
+   host: a converged one within its own tolerance, and each one within
+   its tolerance or at the fp32 floor of the backward error.
 
 ``--chips 4`` runs only the sharded path and its reference:
 ``distributed_solve(pipecg, ..., engine="sharded_fused")`` over a 4-chip
@@ -24,7 +24,7 @@ the device) come first; the last line of standard output is
 Any failed check raises, so the script exits non-zero without that line,
 as it does when JAX finds no TPU.
 
-    python chip_smoke.py [--chips 4] [--seed 1]
+    python chip_smoke.py [--chips 4]
 
 The compile cache is ``$JAX_COMPILATION_CACHE_DIR`` when set, otherwise
 ``.jax_cache`` next to this file.
@@ -40,6 +40,7 @@ import time
 import numpy as np
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
+SEED = 1                 # every right-hand side and random operand
 N = 2 ** 20              # rows per chip
 MODES = 64               # laplacian_mode_rhs: CG needs about this many steps
 MAXITER = 1000
@@ -84,21 +85,6 @@ def true_residual(A, b, x):
     bn = np.linalg.norm(b64)
     return float(r / bn), float(r / (A.inf_norm() * np.linalg.norm(x64)
                                       + bn))
-
-
-def laplacian_solution_norm(b) -> float:
-    """``||A^-1 b||`` for the 1-D Dirichlet Laplacian tridiag(-1, 2, -1).
-
-    Its Green's function is ``(A^-1)_ij = min(i, j) (n + 1 - max(i, j)) /
-    (n + 1)`` (1-based), so ``A^-1 b`` is two cumulative sums in float64.
-    """
-    b64 = np.asarray(b, np.float64)
-    n = b64.size
-    i = np.arange(1, n + 1, dtype=np.float64)
-    lower = np.cumsum(i * b64)                       # sum_{j <= i} j b_j
-    upper = np.cumsum(((n + 1 - i) * b64)[::-1])[::-1]
-    upper = np.append(upper[1:], 0.0)                # sum_{j > i} (n+1-j) b_j
-    return float(np.linalg.norm(((n + 1 - i) * lower + i * upper) / (n + 1)))
 
 
 def compile_with_kernel(fn, *args):
@@ -189,7 +175,7 @@ def phase_kernels(n: int, rng: np.random.Generator) -> None:
     require(max(red_errs) <= 1e-4, "fused sweep reduction row matches")
 
 
-def phase_solve(n: int, seed: int) -> None:
+def phase_solve(n: int) -> None:
     """pipecg (fused single sweep) and cg on the 1-D Laplacian."""
     import jax.numpy as jnp
     from repro.core.krylov import cg, pipecg, tridiagonal_laplacian
@@ -198,7 +184,7 @@ def phase_solve(n: int, seed: int) -> None:
     from repro.serve.load import laplacian_mode_rhs
 
     A = tridiagonal_laplacian(n, dtype=jnp.float32)
-    b = laplacian_mode_rhs(n, MODES, np.random.default_rng(seed))
+    b = laplacian_mode_rhs(n, MODES, np.random.default_rng(SEED))
     b = jnp.asarray(b, jnp.float32)
     opts = SolverOptions(engine="fused", M="jacobi", maxiter=MAXITER, tol=TOL)
     for name, solver in (("pipecg", pipecg), ("cg", cg)):
@@ -216,7 +202,7 @@ def phase_solve(n: int, seed: int) -> None:
         require(eta <= ETA_TOL, f"{name} backward error <= {ETA_TOL:.3g}")
 
 
-def phase_serve(n: int, seed: int) -> None:
+def phase_serve(n: int) -> None:
     """SolverServer with the multi-RHS fused sweep (k = 8 slots)."""
     import jax.numpy as jnp
     from repro.core.krylov import tridiagonal_laplacian
@@ -227,7 +213,7 @@ def phase_serve(n: int, seed: int) -> None:
     A = tridiagonal_laplacian(n, dtype=jnp.float32)
     reqs = synthetic_requests(A, 12, tol=1e-4, maxiter=400,
                               modes=(MODES // 2, MODES), M="jacobi",
-                              seed=seed)
+                              seed=SEED)
     server = SolverServer(k_slots=8, options=SolverOptions(engine="fused"))
     t0 = time.perf_counter()
     server.warmup(reqs[0])
@@ -241,32 +227,35 @@ def phase_serve(n: int, seed: int) -> None:
     stats = server.run()
     recs = {r.rid: r for r in server.records}
     require(sorted(recs) == [r.rid for r in reqs], "every request answered")
-    attainable = []
+    info(phase="serve", n=n, k_slots=8, requests=len(reqs),
+         server_converged=stats.n_converged, restarts=stats.restarts,
+         warmup_s=warm, wall_s=stats.wall_s, latency=stats.latency.as_dict())
+    failed = []
     for req in reqs:
         rec = recs[req.rid]
         require(rec.x is not None, f"request {req.rid} returned an answer")
-        rel, _ = true_residual(A, req.b, rec.x)
-        # the server's own contract: converged means the host float64
-        # residual met the request's relative tolerance
-        require(not rec.converged or rel <= req.tol * 1.01,
-                f"request {req.rid} converged within tol={req.tol}")
-        # a relative tolerance is attainable in fp32 when it lies above
-        # the backward-error gate: tol ||b|| >= ETA_TOL (||A|| ||x*|| + ||b||)
+        rel, eta = true_residual(A, req.b, rec.x)
+        # the request's relative tolerance, as a backward error of x
         bn = np.linalg.norm(np.asarray(req.b, np.float64))
-        if req.tol * bn >= ETA_TOL * (A.inf_norm()
-                                      * laplacian_solution_norm(req.b) + bn):
-            attainable.append(req.rid)
-            require(rec.converged, f"request {req.rid} (tolerance above "
-                    "the fp32 floor) converged")
-    info(phase="serve", n=n, k_slots=8, requests=len(reqs),
-         server_converged=stats.n_converged, attainable=len(attainable),
-         restarts=stats.restarts, iters=[recs[r.rid].iters for r in reqs],
-         warmup_s=warm, wall_s=stats.wall_s,
-         latency=stats.latency.as_dict())
-    require(attainable, "some request's tolerance lies above the fp32 floor")
+        eta_tol = 1.01 * req.tol * bn / (
+            A.inf_norm() * np.linalg.norm(np.asarray(rec.x, np.float64)) + bn)
+        info(phase="serve", rid=req.rid, converged=rec.converged,
+             iters=rec.iters, restarts=rec.restarts, rel_residual=rel,
+             backward_error_eps=eta / EPS32, tol_eps=eta_tol / EPS32)
+        # the server's contract: converged means the host float64
+        # residual met the request's tolerance; any other answer must be
+        # as good as fp32 allows
+        if rec.converged and rel > 1.01 * req.tol:
+            failed.append(f"request {req.rid} converged, rel {rel:.3g} "
+                          f"> tol {req.tol}")
+        if eta > max(ETA_TOL, eta_tol):
+            failed.append(f"request {req.rid} backward error "
+                          f"{eta / EPS32:.3g} eps > max(32, "
+                          f"{eta_tol / EPS32:.3g}) eps")
+    require(not failed, "; ".join(failed))
 
 
-def phase_sharded(n_per_chip: int, seed: int, chips: int) -> None:
+def phase_sharded(n_per_chip: int, chips: int) -> None:
     """distributed_solve(pipecg, engine="sharded_fused") over ``chips``."""
     import jax
     import jax.numpy as jnp
@@ -283,7 +272,7 @@ def phase_sharded(n_per_chip: int, seed: int, chips: int) -> None:
     mesh = Mesh(np.array(devs), ("shards",))
     n = chips * n_per_chip
     A = tridiagonal_laplacian(n, dtype=jnp.float32)
-    b = laplacian_mode_rhs(n, MODES, np.random.default_rng(seed))
+    b = laplacian_mode_rhs(n, MODES, np.random.default_rng(SEED))
     bands = jax.device_put(A.bands, NamedSharding(mesh, P(None, "shards")))
     bd = jax.device_put(jnp.asarray(b, jnp.float32),
                         NamedSharding(mesh, P("shards")))
@@ -317,8 +306,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4: run only the sharded path over a 4-chip mesh")
-    ap.add_argument("--seed", type=int, default=1,
-                    help="seed of every right-hand side and operand")
     args = ap.parse_args(argv)
 
     import jax
@@ -334,11 +321,11 @@ def main(argv=None) -> int:
     sys.path.insert(0, os.path.join(ROOT, "src"))
 
     if args.chips == 1:
-        phase_kernels(N, np.random.default_rng(args.seed))
-        phase_solve(N, args.seed)
-        phase_serve(N, args.seed)
+        phase_kernels(N, np.random.default_rng(SEED))
+        phase_solve(N)
+        phase_serve(N)
     else:
-        phase_sharded(N, args.seed, args.chips)
+        phase_sharded(N, args.chips)
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

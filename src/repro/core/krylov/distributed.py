@@ -373,6 +373,10 @@ def sharded_pipecg_solve(offsets: Tuple[int, ...], bands_local, b_local, *,
         state0.update(efu_l=ef0, efu_r=ef0, efp_l=ef0, efp_r=ef0)
     bb = jax.lax.psum(jnp.sum(B * B, axis=-1), axis_name)
     tol2 = jnp.asarray(tol, dt) ** 2 * bb
+    # the sweep's block and operator split, once per solve: no iteration
+    # re-slices, re-pads or re-sums the loop-invariant operator
+    blk, hop = kops.pipecg_halo_operator(offsets, bands_ext, invd_ext, x, u,
+                                         block=block, n_shards=n_shards)
 
     def step(st, _):
         # ---- halo exchange for THIS iteration's sweep: depends only on
@@ -399,8 +403,8 @@ def sharded_pipecg_solve(offsets: Tuple[int, ...], bands_local, b_local, *,
         alpha = jnp.where(st["first"], gamma / delta,
                           gamma / (delta - beta * gamma / st["alpha_prev"]))
         x, r, u, p, red_new = kops.pipecg_spmv_halo_step(
-            offsets, bands_ext, invd_ext, st["x"], st["r"], st["u"], st["p"],
-            ul, ur, pl_, pr, alpha, beta, block=block, n_shards=n_shards)
+            offsets, hop, st["x"], st["r"], st["u"], st["p"],
+            ul, ur, pl_, pr, alpha, beta, block=blk)
         if wire_gram:
             # squeeze the partial reduction through the int8 wire grid
             # BEFORE the carry: the psum count and dataflow — the HLO

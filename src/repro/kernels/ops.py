@@ -261,21 +261,16 @@ def pipecg_spmv_fused_step(offsets: Tuple[int, ...], bands, inv_diag,
     return outs
 
 
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("block", "n_shards"))
-def pipecg_spmv_halo_step(offsets: Tuple[int, ...], bands_ext, invd_ext,
-                          x, r, u, p, u_left, u_right, p_left, p_right,
-                          alpha, beta, block: int = None, n_shards: int = 1):
-    """Per-shard single-sweep PIPECG iteration with neighbor halos.
+def pipecg_halo_operator(offsets: Tuple[int, ...], bands_ext, invd_ext, x, u,
+                         block: int = None, n_shards: int = 1):
+    """Once per solve: the halo sweep's block and its split operator.
 
-    Vectors are (k, n_local); ``u_left``/``u_right``/``p_left``/``p_right``
-    are the (k, 2*halo) ppermute payloads; ``bands_ext`` / ``invd_ext``
-    the once-per-solve halo-extended operator.  Returns (x', r', u', p',
-    red) where ``red`` (k, 6) is this shard's PARTIAL reduction row
-    including the ABFT checksum entry red[:, 5] (the caller psums it).
-    The default block is autotuned on (backend, n_local, n_shards,
-    k_rhs) — repeated campaign runs reuse the on-disk cache
-    (kernels/autotune.py).
+    ``bands_ext`` / ``invd_ext`` are the halo-extended local operator;
+    ``x`` (k, n_local) and ``u`` give the RHS count and the solve and
+    storage dtypes (r and p are carried in u's).  The default block is
+    autotuned on (backend, n_local, n_shards, k_rhs) — repeated campaign
+    runs reuse the on-disk cache (kernels/autotune.py).  Returns
+    ``(block, op)`` for :func:`pipecg_spmv_halo_step`.
     """
     k_rhs, n = x.shape
     halo = max(abs(o) for o in offsets)
@@ -286,7 +281,26 @@ def pipecg_spmv_halo_step(offsets: Tuple[int, ...], bands_ext, invd_ext,
     if block is None:
         block = _sweep_block("pipecg_spmv_halo", offsets, bands_ext, x, u,
                              n_shards=n_shards, k_rhs=k_rhs)
-    return _ps.pipecg_spmv_halo(offsets, bands_ext, invd_ext, x, r, u, p,
+    hb = _ps.halo_rows(offsets, bands_ext.dtype, invd_ext.dtype, x.dtype,
+                       u.dtype)
+    block = stencil.legal_block(min(block, n), hb)
+    return block, _ps.halo_operator(offsets, bands_ext, invd_ext,
+                                    block=block, hb=hb)
+
+
+@functools.partial(jax.jit, static_argnums=(0,), static_argnames=("block",))
+def pipecg_spmv_halo_step(offsets: Tuple[int, ...], op, x, r, u, p,
+                          u_left, u_right, p_left, p_right, alpha, beta, *,
+                          block: int):
+    """Per-shard single-sweep PIPECG iteration with neighbor halos.
+
+    Vectors are (k, n_local); ``u_left``/``u_right``/``p_left``/``p_right``
+    are the (k, 2*halo) ppermute payloads; ``block`` and ``op`` come from
+    :func:`pipecg_halo_operator`, once per solve.  Returns (x', r', u',
+    p', red) where ``red`` (k, 6) is this shard's PARTIAL reduction row
+    including the ABFT checksum entry red[:, 5] (the caller psums it).
+    """
+    return _ps.pipecg_spmv_halo(offsets, op, x, r, u, p,
                                 (u_left, u_right), (p_left, p_right),
                                 alpha, beta, block=block,
                                 interpret=_interpret())

@@ -56,10 +56,10 @@ words for the tridiagonal operator (vs 13n) — measured and gated by the
 of zero edge rows, the caller passes the 2h left/right rows of u/p
 received from its ring neighbors (``lax.ppermute`` inside shard_map) and
 an operator (bands, diag^-1) pre-extended by h with the neighbors' rows —
-loop-invariant, exchanged once per solve.  The kernel body is identical;
-only the provenance of the edge rows differs, so one local iteration
-(updates + Jacobi + DIA SpMV + partial dots) still costs one HBM pass per
-shard, and the emitted reduction row is a PARTIAL sum the distributed
+loop-invariant, exchanged and split (``halo_operator``) once per solve.
+The kernel body is identical; only the provenance of the edge rows
+differs, so one local iteration (updates + Jacobi + DIA SpMV + partial
+dots) still costs one HBM pass per shard, and the emitted reduction row is a PARTIAL sum the distributed
 driver finishes with a deferred psum (split-phase, see
 core/krylov/distributed.py).  When the local row count is padded to the
 block size, halo rows leak real (neighbor) values into the pad region, so
@@ -68,7 +68,7 @@ the kernel masks rows >= n_valid out of the reduction partials.
 from __future__ import annotations
 
 import functools
-from typing import Sequence, Tuple
+from typing import NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -249,11 +249,66 @@ def pipecg_spmv_fused(offsets: Sequence[int], bands: jnp.ndarray,
     return tuple(o[:, :n] for o in outs[:4]) + (outs[4],)
 
 
-def pipecg_spmv_halo(offsets: Sequence[int], bands_ext: jnp.ndarray,
-                     invd_ext: jnp.ndarray, x, r, u, p,
+class HaloOperator(NamedTuple):
+    """The loop-invariant operand of :func:`pipecg_spmv_halo`.
+
+    Built once per solve by :func:`halo_operator`, so no iteration
+    slices, pads, re-tiles or re-sums the operator.  ``bands``, ``invd``
+    and ``csum`` are in row layout (stencil.as_rows: (lead, m / 128,
+    128), lead n_bands, 1 and 1) and span the sweep's m rows: the local
+    n rows, and on a padded sweep (m > n) the right halo rows and zeros
+    after them.  ``bands_edges`` / ``invd_edges`` are the edge operands
+    (stencil.edges) of the rows just outside the sweep.
+    """
+
+    bands: jnp.ndarray
+    invd: jnp.ndarray
+    csum: jnp.ndarray
+    bands_edges: jnp.ndarray
+    invd_edges: jnp.ndarray
+
+
+def halo_operator(offsets: Sequence[int], bands_ext: jnp.ndarray,
+                  invd_ext: jnp.ndarray, *, block: int, hb: int
+                  ) -> HaloOperator:
+    """Split the halo-extended operator for sweeps of ``block`` rows.
+
+    ``bands_ext`` (n_bands, n + 2*halo) / ``invd_ext`` (n + 2*halo,) are
+    the local operator rows pre-extended by ``halo`` per side with the
+    neighbors' values.  ``block`` must be a legal block for ``hb``
+    window rows (stencil.legal_block).  When n is not a multiple of it
+    the sweep is padded: [local rows | right halo | zeros], so row n-1's
+    stencil reads the neighbor rows at n..n+2h-1 and the right edge
+    operand is empty.  The column sums are taken from ``bands_ext``
+    (halo=h), i.e. the local slice of the GLOBAL c = A^T 1 including
+    neighbor-row contributions, so the psum of the per-shard checksum
+    partials reproduces the exact global checksum residual.
+    """
+    halo = max(abs(o) for o in offsets)
+    n = bands_ext.shape[-1] - 2 * halo
+    assert block % (hb * LANE) == 0, (block, hb)
+    csum = dia_column_checksum(offsets, bands_ext, halo=halo)
+    bands_l, bands_r = bands_ext[:, :halo], bands_ext[:, n + halo:]
+    invd_l, invd_r = invd_ext[None, :halo], invd_ext[None, n + halo:]
+    bands, invd = bands_ext[:, halo:n + halo], invd_ext[None, halo:n + halo]
+    if n % block:
+        n_pad = -(-(n + 2 * halo) // block) * block
+        ext = lambda v, right: jnp.concatenate(
+            [v, right, jnp.zeros(v.shape[:-1] + (n_pad - n - right.shape[-1],),
+                                 v.dtype)], axis=-1)
+        bands, invd = ext(bands, bands_r), ext(invd, invd_r)
+        csum = jnp.pad(csum, (0, n_pad - n))
+        bands_r, invd_r = bands_r[:, :0], invd_r[:, :0]
+    rows = stencil.as_rows
+    return HaloOperator(rows(bands), rows(invd), rows(csum),
+                        stencil.edges(bands_l, bands_r, hb),
+                        stencil.edges(invd_l, invd_r, hb))
+
+
+def pipecg_spmv_halo(offsets: Sequence[int], op: HaloOperator, x, r, u, p,
                      u_lr: Tuple[jnp.ndarray, jnp.ndarray],
                      p_lr: Tuple[jnp.ndarray, jnp.ndarray], alpha, beta, *,
-                     block: int = DEFAULT_BLOCK, interpret: bool = False
+                     block: int, interpret: bool = False
                      ) -> Tuple[jnp.ndarray, ...]:
     """Sharded single-sweep PIPECG iteration with neighbor-supplied halos.
 
@@ -264,57 +319,42 @@ def pipecg_spmv_halo(offsets: Sequence[int], bands_ext: jnp.ndarray,
       per side, shaped (k, 2*halo) — the ``lax.ppermute`` payload of this
       iteration (chain-boundary shards pass zeros, matching the global
       zero extension of the DIA bands).
-    * ``bands_ext`` (n_bands, n + 2*halo) / ``invd_ext`` (n + 2*halo,):
-      operator rows pre-extended by ``halo`` per side with the neighbors'
-      values — loop-invariant, exchanged once per solve.
+    * ``op``: the operator split by :func:`halo_operator` for this
+      ``block``, once per solve.
 
-    ``block`` is rounded up to whole halo windows.  When n is not a
-    multiple of it the rows are padded, the right halo rows
-    sit right after row n-1, and pad rows are masked out of the
-    reduction partials (they see halo data, not zeros).  The returned
-    ``red`` (k, 6) holds this shard's PARTIAL sums — the caller must
-    finish them with a ``psum`` over the mesh axis.  That includes the
-    checksum entry red[:, 5]: the column sums are computed from
-    ``bands_ext`` (halo=h), i.e. the local slice of the GLOBAL c = A^T 1
-    including neighbor-row contributions, so the psum of the per-shard
-    row/column partials reproduces the exact global checksum residual
-    with no extra communication.
+    When the operator's sweep is padded past n, u and p get the same
+    [rows | right halo | zeros] layout, and pad rows are masked out of
+    the reduction partials (they see halo data, not zeros).  The
+    returned ``red`` (k, 6) holds this shard's PARTIAL sums — the caller
+    must finish them with a ``psum`` over the mesh axis, the checksum
+    entry red[:, 5] included.
     """
     k_rhs, n = x.shape
     halo = max(abs(o) for o in offsets)
     u_l, u_r = u_lr
     p_l, p_r = p_lr
     assert u_l.shape == (k_rhs, 2 * halo), (u_l.shape, k_rhs, halo)
-    hb = halo_rows(offsets, bands_ext.dtype, invd_ext.dtype, x.dtype,
-                   r.dtype, u.dtype, p.dtype)
-    block = stencil.legal_block(min(block, n), hb)
-    csum = dia_column_checksum(offsets, bands_ext, halo=halo)
-    bands_lr = (bands_ext[:, :halo], bands_ext[:, n + halo:])
-    invd_lr = (invd_ext[None, :halo], invd_ext[None, n + halo:])
-    bands, invd = bands_ext[:, halo:n + halo], invd_ext[halo:n + halo]
+    hb = op.bands_edges.shape[1] // 2
+    assert hb == halo_rows(offsets, op.bands.dtype, op.invd.dtype, x.dtype,
+                           r.dtype, u.dtype, p.dtype), hb
     # pads match each carried array's storage dtype so a bf16 policy
     # stays bf16 end to end
     u_l, u_r, p_l, p_r = (v.astype(w.dtype)
                           for v, w in ((u_l, u), (u_r, u), (p_l, p), (p_r, p)))
+    n_sweep = op.bands.shape[1] * LANE
     n_valid = None
-    if n % block:
-        # [local rows | right halo | zero pad]: row n-1's stencil reads
-        # the neighbor rows at n..n+2h-1, and the edge operand is zero
+    if n_sweep != n:
         n_valid = n
-        n_pad = -(-(n + 2 * halo) // block) * block
         ext = lambda v, right: jnp.concatenate(
-            [v, right, jnp.zeros(v.shape[:-1] + (n_pad - n - right.shape[-1],),
-                                 v.dtype)], axis=-1)
-        bands, invd = ext(bands, bands_lr[1]), ext(invd[None], invd_lr[1])[0]
+            [v, right, jnp.zeros(v.shape[:-1]
+                                 + (n_sweep - n - right.shape[-1],), v.dtype)],
+            axis=-1)
         u, p = ext(u, u_r), ext(p, p_r)
-        csum, x, r = (jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, n_pad - n)])
-                      for v in (csum, x, r))
-        bands_lr = (bands_lr[0], bands_lr[1][:, :0])
-        invd_lr = (invd_lr[0], invd_lr[1][:, :0])
+        x, r = (jnp.pad(v, ((0, 0), (0, n_sweep - n))) for v in (x, r))
         u_r, p_r = u_r[:, :0], p_r[:, :0]
-    edges = (stencil.edges(*bands_lr, hb), stencil.edges(*invd_lr, hb),
+    edges = (op.bands_edges, op.invd_edges,
              stencil.edges(u_l, u_r, hb), stencil.edges(p_l, p_r, hb))
-    outs = _sweep(offsets, bands, invd, csum, u, p, x, r,
+    outs = _sweep(offsets, op.bands, op.invd, op.csum, u, p, x, r,
                   _ab(alpha, beta, k_rhs, x.dtype), edges, block=block,
                   n_valid=n_valid, interpret=interpret)
     if n_valid is not None:

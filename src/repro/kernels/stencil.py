@@ -124,7 +124,12 @@ def partials_tile(sums: Sequence[jnp.ndarray], acc) -> jnp.ndarray:
 
 
 def as_rows(v: jnp.ndarray) -> jnp.ndarray:
-    """``(..., n)`` -> ``(lead, n / 128, 128)``; 1-D input gets lead 1."""
+    """``(..., n)`` -> ``(lead, n / 128, 128)``; 1-D input gets lead 1.
+
+    A ``(lead, rows, 128)`` operand is already in row layout.
+    """
+    if v.ndim == 3:
+        return v
     lead = v.shape[0] if v.ndim == 2 else 1
     return v.reshape(lead, v.shape[-1] // LANE, LANE)
 

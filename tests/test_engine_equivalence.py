@@ -204,14 +204,17 @@ def _manual_sharded_step(A, invd, x, r, u, p, alpha, beta, shards,
     outs, red = [], 0.0
     for s in range(shards):
         lo = s * nl
-        piece = kops.pipecg_spmv_halo_step(
+        blk, op = kops.pipecg_halo_operator(
             offsets, bands_g[:, lo:lo + nl + 2 * h],
-            invd_g[lo:lo + nl + 2 * h],
+            invd_g[lo:lo + nl + 2 * h], x[:, lo:lo + nl], u[:, lo:lo + nl],
+            block=block, n_shards=shards)
+        piece = kops.pipecg_spmv_halo_step(
+            offsets, op,
             x[:, lo:lo + nl], r[:, lo:lo + nl], u[:, lo:lo + nl],
             p[:, lo:lo + nl],
             u_g[:, lo:lo + 2 * h], u_g[:, lo + nl + 2 * h:lo + nl + 4 * h],
             p_g[:, lo:lo + 2 * h], p_g[:, lo + nl + 2 * h:lo + nl + 4 * h],
-            alpha, beta, block=block, n_shards=shards)
+            alpha, beta, block=blk)
         outs.append(piece[:4])
         red = red + piece[4]
     return tuple(jnp.concatenate([o[i] for o in outs], axis=-1)

@@ -6,12 +6,13 @@ unaligned dynamic loads, ``dynamic_slice`` on in-register values, scalar
 stores into VMEM, and kernels whose blocks overflow the scoped VMEM.
 Nothing runs; a pass says only that the chip's compiler takes the
 program.  Sizes are a deployment's per-chip share (2^20 fp32 rows), at
-the block the autotuner picks.
+the block the autotuner picks, and the largest rows per chip that fit.
 
 The topology is described inside a module fixture, never at import:
 only one process may hold the TPU library at a time.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
+from repro.launch import hlo_analysis
 
 N = 2 ** 20
 OFFSETS = (-1, 0, 1)          # the ex23 tridiagonal Laplacian
@@ -71,13 +73,19 @@ def test_spmv_dia_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("k_rhs", [1, 8])
-def test_pipecg_spmv_fused_compiles(one_chip, k_rhs):
-    """Single-chip sweep; k = 8 is the SolverServer's multi-RHS batch."""
-    vec = _spec(one_chip, k_rhs, N)
+@pytest.mark.parametrize("k_rhs,n", [(1, N), (8, N), (1, 2 ** 26),
+                                     (8, 2 ** 24)],
+                         ids=["1", "8", "1-largest", "8-largest"])
+def test_pipecg_spmv_fused_compiles(one_chip, k_rhs, n):
+    """Single-chip sweep; k = 8 is the SolverServer's multi-RHS batch.
+
+    The largest rows per chip that compile: nothing is VMEM-resident, so
+    the bound is the 16 GB of HBM (k = 8 at 2^26 rows exceeds it).
+    """
+    vec = _spec(one_chip, k_rhs, n)
     text = _compiled_text(
         lambda *a: ops.pipecg_spmv_fused_step(OFFSETS, *a),
-        _spec(one_chip, len(OFFSETS), N), _spec(one_chip, N),
+        _spec(one_chip, len(OFFSETS), n), _spec(one_chip, n),
         vec, vec, vec, vec, _spec(one_chip, k_rhs), _spec(one_chip, k_rhs))
     assert "tpu_custom_call" in text
 
@@ -93,9 +101,15 @@ def test_pipecg_fused_update_kernel_compiles(one_chip):
 def test_pipecg_spmv_halo_compiles(one_chip):
     """Per-shard sweep at 2^20 local rows, neighbor halo payloads."""
     vec, edge = _spec(one_chip, 1, N), _spec(one_chip, 1, 2 * HALO)
+
+    def sweep(bands_ext, invd_ext, x, r, u, p, *rest):
+        blk, op = ops.pipecg_halo_operator(OFFSETS, bands_ext, invd_ext, x, u,
+                                           n_shards=4)
+        return ops.pipecg_spmv_halo_step(OFFSETS, op, x, r, u, p, *rest,
+                                         block=blk)
+
     text = _compiled_text(
-        lambda *a: ops.pipecg_spmv_halo_step(OFFSETS, *a, n_shards=4),
-        _spec(one_chip, len(OFFSETS), N + 2 * HALO),
+        sweep, _spec(one_chip, len(OFFSETS), N + 2 * HALO),
         _spec(one_chip, N + 2 * HALO), vec, vec, vec, vec,
         edge, edge, edge, edge, _spec(one_chip, 1), _spec(one_chip, 1))
     assert "tpu_custom_call" in text
@@ -108,7 +122,6 @@ def test_sharded_fused_solve_compiles_on_four_chips(topo, monkeypatch):
     from repro.core.krylov.distributed import distributed_solve
     from repro.core.krylov.operators import DiaMatrix
     from repro.core.krylov.options import SolverOptions
-    from repro.launch.hlo_analysis import split_phase_overlap
 
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     mesh = Mesh(np.array(topo.devices), ("shards",))
@@ -121,6 +134,13 @@ def test_sharded_fused_solve_compiles_on_four_chips(topo, monkeypatch):
         _spec(NamedSharding(mesh, P(None, "shards")), len(OFFSETS), n),
         _spec(NamedSharding(mesh, P("shards")), n))
     assert "tpu_custom_call" in text
-    overlap = split_phase_overlap(text)
+    overlap = hlo_analysis.split_phase_overlap(text)
     assert overlap["overlap_ok"], overlap
     assert [b["all_reduce"] for b in overlap["bodies"].values()] == [1]
+    # the operator is split and tiled once per solve: no loop body
+    # computes a band-shaped (3, ...) array (copy, slice, fusion, ...)
+    comps = hlo_analysis._split_computations(text)
+    made = [ln for body in overlap["bodies"] for ln in comps[body]
+            if re.match(r"%?[\w.\-]+ = f32\[3,\S* (?!get-tuple-element|"
+                        r"bitcast|parameter)", ln)]
+    assert not made, made
