@@ -309,6 +309,7 @@ def _pipecg_engine(A, b, x0=None, *, maxiter=100, tol=0.0, M=None,
         # storage width from here on
         vecs = dict(vecs, r=vecs["r"].astype(sdt), u=vecs["u"].astype(sdt),
                     p=vecs["p"].astype(sdt))
+    A_iter = eng.pipecg_operator(A_iter, M, vecs)
     one = jnp.ones_like(gamma)
     state0 = dict(vecs=vecs, gamma=gamma, delta=delta,
                   gamma_prev=one, alpha_prev=one,

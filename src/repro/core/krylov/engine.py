@@ -32,7 +32,7 @@ The registry is open: third-party engines register with
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional, Tuple, Union
+from typing import Callable, Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -126,6 +126,21 @@ class Engine:
 
     def pipecg_iter(self, A, M, ip: str, vecs, alpha, beta):
         raise NotImplementedError
+
+    def pipecg_operator(self, A, M, vecs):
+        """The operator ``pipecg_iter`` takes, made once before the loop.
+
+        ``vecs`` is the state from ``pipecg_init``.  The default is ``A``.
+        """
+        return A
+
+
+class _DiaSweep(NamedTuple):
+    """A DIA operator split once per solve for the single-sweep kernel."""
+
+    offsets: Tuple[int, ...]
+    block: int
+    op: object        # kernels/pipecg_spmv_fused.py::HaloOperator
 
 
 def _ip_pick(ip: str, ru, wu, rw, ww):
@@ -239,8 +254,8 @@ class FusedEngine(Engine):
         delta = _rdot(w, u) if ip == "id" else _rdot(w, w)
         if inv_d is not None:
             # single-sweep path: only (x, r, u, p) round-trip HBM per
-            # iteration (diag^-1 is re-derived in pipecg_iter from the
-            # trace-constant A — loop-invariant, hoisted out of the scan)
+            # iteration (a DIA operator and its diag^-1 are split once
+            # per solve by pipecg_operator)
             return dict(x=x, r=r, u=u, p=jnp.zeros_like(b)), gamma, delta
         # fallback: update-kernel path carries the full 10-vector state
         m = Mf(w)
@@ -250,25 +265,40 @@ class FusedEngine(Engine):
                     z=zero, q=zero, s=zero, p=zero)
         return vecs, gamma, delta
 
+    def pipecg_operator(self, A, M, vecs):
+        """Split a DIA operator for the single sweep, once per solve.
+
+        The split (row layout, diag^-1, column checksum) is
+        loop-invariant; made inside the loop it would be re-done every
+        iteration.  diag^-1 takes the OPERATOR's dtype, not x's: under a
+        storage-demoting PrecisionPolicy the operator rides in bf16/fp8
+        while x stays at accum precision.
+        """
+        if "w" in vecs or getattr(A, "format", None) != "dia":
+            return A
+        from repro.kernels import ops as kops
+
+        inv_d = _jacobi_inv_diag(A, M, vecs["x"].shape[-1], A.dtype)
+        block, op = kops.pipecg_sweep_operator(A.offsets, A.bands, inv_d,
+                                               vecs["x"], vecs["u"])
+        return _DiaSweep(A.offsets, block, op)
+
     def pipecg_iter(self, A, M, ip, st, alpha, beta):
         from repro.kernels import ops as kops
 
         if "w" not in st:  # single-sweep mega-kernel state
-            # loop-invariant under jit (A is a trace constant): XLA hoists
-            # the 1/diag out of the scan.  dtype follows the OPERATOR, not
-            # x: under a storage-demoting PrecisionPolicy the operator
-            # rides in bf16/fp8 while x stays at accum precision, and
-            # diag^-1 must match the resident-operand dtype the kernel
-            # streams.  Format branch: DIA -> stencil sweep, BSR ->
-            # blocked-ELL gather sweep (kernels/spmv_bsr.py).
-            inv_d = _jacobi_inv_diag(A, M, st["x"].shape[-1], A.dtype)
-            if A.format == "bsr":
+            # Format branch: DIA -> stencil sweep, BSR -> blocked-ELL
+            # gather sweep (kernels/spmv_bsr.py)
+            if getattr(A, "format", None) == "dia":  # the caller did not
+                A = self.pipecg_operator(A, M, st)   # split it: split here
+            if isinstance(A, _DiaSweep):
+                x, r, u, p, red = kops.pipecg_sweep_step(
+                    A.offsets, A.op, st["x"], st["r"], st["u"], st["p"],
+                    alpha, beta, block=A.block)
+            else:
+                inv_d = _jacobi_inv_diag(A, M, st["x"].shape[-1], A.dtype)
                 x, r, u, p, red = kops.pipecg_bsr_fused_step(
                     A.indices, A.blocks, inv_d,
-                    st["x"], st["r"], st["u"], st["p"], alpha, beta)
-            else:
-                x, r, u, p, red = kops.pipecg_spmv_fused_step(
-                    A.offsets, A.bands, inv_d,
                     st["x"], st["r"], st["u"], st["p"], alpha, beta)
             gamma, delta = _ip_pick(ip, red[..., 0], red[..., 1],
                                     red[..., 3], red[..., 4])

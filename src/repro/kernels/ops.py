@@ -237,28 +237,55 @@ def _sweep_block(kind, offsets, bands, x, u, **key):
         dtype_storage=_storage_key(u.dtype, x.dtype), **key)
 
 
+def pipecg_sweep_operator(offsets: Tuple[int, ...], bands, inv_diag, x, u,
+                          block: int = None):
+    """Once per solve on one device: the sweep's block and split operator.
+
+    ``bands`` (n_bands, n) / ``inv_diag`` (n,) are the whole operator;
+    ``x`` ((n,) or (k, n)) and ``u`` give the solve and storage dtypes
+    (r and p are carried in u's).  The default block comes from the
+    autotuner.  Returns ``(block, op)`` for :func:`pipecg_sweep_step`.
+    """
+    if block is None:
+        block = _sweep_block("pipecg_spmv", offsets, bands, x, u)
+    hb = _ps.halo_rows(offsets, bands.dtype, inv_diag.dtype, x.dtype, u.dtype)
+    block = stencil.legal_block(min(block, x.shape[-1]), hb)
+    return block, _ps.local_operator(offsets, bands, inv_diag, block=block,
+                                     hb=hb)
+
+
 @functools.partial(jax.jit, static_argnums=(0,), static_argnames=("block",))
-def pipecg_spmv_fused_step(offsets: Tuple[int, ...], bands, inv_diag,
-                           x, r, u, p, alpha, beta, block: int = None):
+def pipecg_sweep_step(offsets: Tuple[int, ...], op, x, r, u, p, alpha, beta,
+                      *, block: int):
     """Single-sweep PIPECG iteration (updates + Jacobi + SpMV + dots).
 
     Accepts (n,) vectors with scalar alpha/beta, or batched (k, n) vectors
-    with (k,) alpha/beta.  The kernel pads the row dimension to its
-    block; the default block comes from the autotuner.
+    with (k,) alpha/beta.  ``block`` and ``op`` come from
+    :func:`pipecg_sweep_operator`, once per solve.
     """
     squeeze = x.ndim == 1
     if squeeze:
         x, r, u, p = (v[None] for v in (x, r, u, p))
         alpha = jnp.asarray(alpha)[None]
         beta = jnp.asarray(beta)[None]
-    if block is None:
-        block = _sweep_block("pipecg_spmv", offsets, bands, x, u)
-    outs = _ps.pipecg_spmv_fused(offsets, bands, inv_diag, x, r, u, p,
-                                 alpha, beta, block=block,
-                                 interpret=_interpret())
+    outs = _ps.pipecg_spmv_fused(offsets, op, x, r, u, p, alpha, beta,
+                                 block=block, interpret=_interpret())
     if squeeze:
         outs = tuple(o[0] for o in outs)
     return outs
+
+
+@functools.partial(jax.jit, static_argnums=(0,), static_argnames=("block",))
+def pipecg_spmv_fused_step(offsets: Tuple[int, ...], bands, inv_diag,
+                           x, r, u, p, alpha, beta, block: int = None):
+    """One single-sweep PIPECG iteration on an operator split for it.
+
+    A solver splits the operator once per solve instead
+    (:func:`pipecg_sweep_operator` + :func:`pipecg_sweep_step`).
+    """
+    blk, op = pipecg_sweep_operator(offsets, bands, inv_diag, x, u,
+                                    block=block)
+    return pipecg_sweep_step(offsets, op, x, r, u, p, alpha, beta, block=blk)
 
 
 def pipecg_halo_operator(offsets: Tuple[int, ...], bands_ext, invd_ext, x, u,

@@ -211,18 +211,17 @@ def _sweep(offsets, bands, invd, csum, u, p, x, r, ab, edges, *, block: int,
     return vecs + (jnp.sum(outs[4][:, :NRED], axis=-1),)
 
 
-def pipecg_spmv_fused(offsets: Sequence[int], bands: jnp.ndarray,
-                      inv_diag: jnp.ndarray, x, r, u, p, alpha, beta, *,
-                      block: int = DEFAULT_BLOCK, interpret: bool = False
+def pipecg_spmv_fused(offsets: Sequence[int], op: "HaloOperator", x, r, u, p,
+                      alpha, beta, *, block: int, interpret: bool = False
                       ) -> Tuple[jnp.ndarray, ...]:
     """One full preconditioned PIPECG iteration, single HBM sweep.
 
     All vectors are (k, n) — k right-hand sides batched over the leading
-    grid dimension; ``alpha`` / ``beta`` are (k,).  ``bands`` is
-    (n_bands, n), ``inv_diag`` (n,); both are shared across the batch.
-    ``block`` is rounded up to whole halo windows (``halo_rows(...) *
-    128`` rows) and n is zero-padded to it; zero rows stay zero and add
-    nothing to the partials.
+    grid dimension; ``alpha`` / ``beta`` are (k,).  ``op`` is the
+    operator split by :func:`local_operator` for this ``block``, once
+    per solve, and shared across the batch.  When the sweep is longer
+    than n, the vectors are zero-padded to it; zero rows stay zero and
+    add nothing to the partials.
 
     Returns (x', r', u', p', red) with red (k, 6) =
     (<r',u'>, <w',u'>, <r',r'>, <r',w'>, <w',w'>, chk) per RHS, where
@@ -230,20 +229,14 @@ def pipecg_spmv_fused(offsets: Sequence[int], bands: jnp.ndarray,
     in-kernel SpMV (rounding-level unless the sweep was corrupted).
     """
     k_rhs, n = x.shape
-    hb = halo_rows(offsets, bands.dtype, inv_diag.dtype, x.dtype, r.dtype,
-                   u.dtype, p.dtype)
-    block = stencil.legal_block(min(block, n), hb)
-    csum = dia_column_checksum(offsets, bands)
-    pad = (-n) % block
+    hb = op.bands_edges.shape[1] // 2
+    pad = op.bands.shape[1] * LANE - n
     if pad:
-        padded = lambda v: jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, pad)])
-        bands, inv_diag, csum, x, r, u, p = map(
-            padded, (bands, inv_diag, csum, x, r, u, p))
-    edges = (stencil.zero_edges(bands.shape[0], hb, bands.dtype),
-             stencil.zero_edges(1, hb, inv_diag.dtype),
+        x, r, u, p = (jnp.pad(v, ((0, 0), (0, pad))) for v in (x, r, u, p))
+    edges = (op.bands_edges, op.invd_edges,
              stencil.zero_edges(k_rhs, hb, u.dtype),
              stencil.zero_edges(k_rhs, hb, p.dtype))
-    outs = _sweep(offsets, bands, inv_diag, csum, u, p, x, r,
+    outs = _sweep(offsets, op.bands, op.invd, op.csum, u, p, x, r,
                   _ab(alpha, beta, k_rhs, x.dtype), edges, block=block,
                   interpret=interpret)
     return tuple(o[:, :n] for o in outs[:4]) + (outs[4],)
@@ -252,7 +245,8 @@ def pipecg_spmv_fused(offsets: Sequence[int], bands: jnp.ndarray,
 class HaloOperator(NamedTuple):
     """The loop-invariant operand of :func:`pipecg_spmv_halo`.
 
-    Built once per solve by :func:`halo_operator`, so no iteration
+    Built once per solve by :func:`halo_operator` (a shard) or
+    :func:`local_operator` (one device), so no iteration
     slices, pads, re-tiles or re-sums the operator.  ``bands``, ``invd``
     and ``csum`` are in row layout (stencil.as_rows: (lead, m / 128,
     128), lead n_bands, 1 and 1) and span the sweep's m rows: the local
@@ -303,6 +297,20 @@ def halo_operator(offsets: Sequence[int], bands_ext: jnp.ndarray,
     return HaloOperator(rows(bands), rows(invd), rows(csum),
                         stencil.edges(bands_l, bands_r, hb),
                         stencil.edges(invd_l, invd_r, hb))
+
+
+def local_operator(offsets: Sequence[int], bands: jnp.ndarray,
+                   inv_diag: jnp.ndarray, *, block: int, hb: int
+                   ) -> HaloOperator:
+    """One device's operator, split for sweeps of ``block`` rows.
+
+    ``bands`` (n_bands, n) / ``inv_diag`` (n,) are the whole matrix, so
+    the rows outside it are zero: :func:`halo_operator` of the operator
+    extended by zero halos.
+    """
+    halo = max(abs(o) for o in offsets)
+    return halo_operator(offsets, jnp.pad(bands, ((0, 0), (halo, halo))),
+                         jnp.pad(inv_diag, (halo, halo)), block=block, hb=hb)
 
 
 def pipecg_spmv_halo(offsets: Sequence[int], op: HaloOperator, x, r, u, p,

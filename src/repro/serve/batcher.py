@@ -60,7 +60,8 @@ def _build(engine: str, offsets: Tuple[int, ...], n: int, k: int,
 
     def step_fn(bands, state, tol2):
         counts["step"] += 1
-        A = DiaMatrix(offsets=offsets, bands=bands)
+        A = eng.pipecg_operator(DiaMatrix(offsets=offsets, bands=bands), M,
+                                state["vecs"])
 
         def body(st, _):
             alpha, beta = _pipecg_scalars(st)

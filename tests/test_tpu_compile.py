@@ -66,6 +66,19 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _band_shaped_in_loops(text: str) -> list:
+    """Lines of a while body that compute a band-shaped (3, ...) array.
+
+    The operator is split and tiled once per solve, so no loop body
+    should copy, slice or fuse one.
+    """
+    comps = hlo_analysis._split_computations(text)
+    bodies = {m.group(2) for m in hlo_analysis._WHILE_RE.finditer(text)}
+    return [ln for body in bodies for ln in comps[body]
+            if re.match(r"%?[\w.\-]+ = f32\[3,\S* (?!get-tuple-element|"
+                        r"bitcast|parameter)", ln)]
+
+
 def test_spmv_dia_compiles(one_chip):
     text = _compiled_text(
         lambda bands, x_ext: ops.spmv_dia_ext(OFFSETS, bands, x_ext, HALO),
@@ -88,6 +101,22 @@ def test_pipecg_spmv_fused_compiles(one_chip, k_rhs, n):
         _spec(one_chip, len(OFFSETS), n), _spec(one_chip, n),
         vec, vec, vec, vec, _spec(one_chip, k_rhs), _spec(one_chip, k_rhs))
     assert "tpu_custom_call" in text
+
+
+def test_fused_solve_compiles(one_chip):
+    """pipecg(engine="fused", M="jacobi"): the kernel in the loop, and
+    the operator split once per solve, not in every iteration."""
+    from repro.core.krylov import pipecg
+    from repro.core.krylov.operators import DiaMatrix
+    from repro.core.krylov.options import SolverOptions
+
+    opts = SolverOptions(engine="fused", M="jacobi", maxiter=50, tol=1e-6)
+    text = _compiled_text(
+        lambda bands, b: pipecg(DiaMatrix(OFFSETS, bands), b, options=opts),
+        _spec(one_chip, len(OFFSETS), N), _spec(one_chip, N))
+    assert "tpu_custom_call" in text
+    assert "while(" in text
+    assert not _band_shaped_in_loops(text)
 
 
 def test_pipecg_fused_update_kernel_compiles(one_chip):
@@ -137,10 +166,4 @@ def test_sharded_fused_solve_compiles_on_four_chips(topo, monkeypatch):
     overlap = hlo_analysis.split_phase_overlap(text)
     assert overlap["overlap_ok"], overlap
     assert [b["all_reduce"] for b in overlap["bodies"].values()] == [1]
-    # the operator is split and tiled once per solve: no loop body
-    # computes a band-shaped (3, ...) array (copy, slice, fusion, ...)
-    comps = hlo_analysis._split_computations(text)
-    made = [ln for body in overlap["bodies"] for ln in comps[body]
-            if re.match(r"%?[\w.\-]+ = f32\[3,\S* (?!get-tuple-element|"
-                        r"bitcast|parameter)", ln)]
-    assert not made, made
+    assert not _band_shaped_in_loops(text)
