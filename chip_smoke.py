@@ -197,7 +197,7 @@ def phase_solve(n: int) -> None:
         info(phase="solve", solver=name, engine="fused", n=n, iters=iters,
              res_norm=float(res.res_norm), backward_error=eta,
              backward_error_eps=eta / EPS32, compile_s=secs, solve_s=wall,
-             scan_steps=MAXITER)
+             loop_steps=min(iters + 1, MAXITER))
         require(iters < MAXITER, f"{name} reached tol={TOL}")
         require(eta <= ETA_TOL, f"{name} backward error <= {ETA_TOL:.3g}")
 

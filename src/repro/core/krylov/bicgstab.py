@@ -32,8 +32,9 @@ single (6, 6) Gram reduction per iteration):
   recomputes ``r = b - A_hat x`` — and its operator images w, t —
   synchronously every ``rr`` iterations to bound true-residual drift.
 
-The fixed-trip-count ``lax.scan`` + masked-freeze semantics match the
-other solvers; the residual history is emitted from the CARRIED Gram (the
+The loop is a fixed-trip-count ``lax.scan`` with masked freezes (the
+CG / PIPECG loops stop at convergence instead, base.run_until_done); the
+residual history is emitted from the CARRIED Gram (the
 frozen state's own residual), so the tail after convergence is constant
 and equals ``res_norm``.  One fused HBM sweep per iteration for DIA
 operators via ``engine="fused"`` (kernels/pipebicgstab_fused.py); the

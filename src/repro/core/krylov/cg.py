@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.krylov import abft
-from repro.core.krylov.base import SolveResult, as_matvec, local_dot
+from repro.core.krylov.base import (SolveResult, as_matvec, local_dot,
+                                    run_until_done)
 from repro.core.krylov.engine import get_engine
 from repro.core.krylov.options import (UNSET, as_policy, check_supported,
                                        resolve_options)
@@ -44,8 +45,11 @@ def cg(A, b, x0=None, *, maxiter=UNSET, tol=UNSET, M=UNSET, dot=local_dot,
        ip: str = "id", engine=UNSET, options=None) -> SolveResult:
     """Preconditioned CG (ip='id') or CR (ip='A').
 
-    Fixed-trip-count ``lax.scan`` over iterations (the paper forces 5000
-    iterates; masked updates freeze the state once ``tol`` is reached).
+    The loop stops after the step at which ``||r|| <= tol ||b||``
+    (:func:`~repro.core.krylov.base.run_until_done`); ``tol = 0`` runs
+    all ``maxiter`` steps, as the paper's fixed-count timings do.
+    ``res_history`` keeps ``maxiter`` entries: those after the last
+    executed step repeat its entry (``SolveResult``).
 
     ``options=SolverOptions(...)`` is the typed spelling of the solver
     knobs (core/krylov/options.py); the loose ``maxiter=/tol=/M=/engine=``
@@ -84,7 +88,7 @@ def cg(A, b, x0=None, *, maxiter=UNSET, tol=UNSET, M=UNSET, dot=local_dot,
                   done=jnp.asarray(False), iters=jnp.asarray(0, jnp.int32))
     tol2 = jnp.asarray(tol, b.dtype) ** 2 * dot(b, b)
 
-    def step(st, _):
+    def step(st):
         pAp = _ip_dots(ip, st["p"], st["p"], st["s"], dot)[1]  # <s,p> or <s,s>
         alpha = st["gamma"] / pAp
         x = st["x"] + alpha * st["p"]
@@ -99,12 +103,9 @@ def cg(A, b, x0=None, *, maxiter=UNSET, tol=UNSET, M=UNSET, dot=local_dot,
         done = st["done"] | (rr <= tol2)
         new = dict(x=x, r=r, u=u, w=w, p=p, s=s, gamma=gamma_new, done=done,
                    iters=st["iters"] + (~done).astype(jnp.int32))
-        # freeze once converged (masked update keeps trip count static)
-        new = jax.tree.map(
-            lambda n, o: jnp.where(st["done"], o, n), new, st)
         return new, jnp.sqrt(jnp.maximum(rr, 0.0))
 
-    st, hist = jax.lax.scan(step, state0, None, length=maxiter)
+    st, hist, _ = run_until_done(step, state0, maxiter)
     res = jnp.sqrt(jnp.maximum(dot(st["r"], st["r"]), 0.0))
     return SolveResult(x=st["x"], iters=st["iters"], res_norm=res,
                        res_history=hist)
@@ -133,6 +134,13 @@ def pipecg(A, b, x0=None, *, maxiter=UNSET, tol=UNSET, M=UNSET,
     ``options=SolverOptions(...)`` is the typed spelling of the solver
     knobs (core/krylov/options.py); the loose kwargs keep working through
     the deprecation shim and resolve to the identical code path.
+
+    The loop stops after the step at which ``||r|| <= tol ||b||`` (all
+    ``maxiter`` steps when ``tol = 0``); ``res_history`` keeps
+    ``maxiter`` entries, those after the last executed step repeating
+    its entry (``SolveResult``).  Several right-hand sides
+    (``pipecg_multi``) freeze each converged column until the last one
+    is done.
 
     ``engine`` ("naive" / "fused" / Engine / None) routes the whole
     iteration through an iteration engine (see core/krylov/engine.py);
@@ -196,7 +204,7 @@ def pipecg(A, b, x0=None, *, maxiter=UNSET, tol=UNSET, M=UNSET,
                   done=jnp.asarray(False), iters=jnp.asarray(0, jnp.int32))
     tol2 = jnp.asarray(tol, b.dtype) ** 2 * dot(b, b)
 
-    def step(st, _):
+    def step(st):
         gamma, delta = st["gamma"], st["delta"]
         beta = jnp.where(st["first"], 0.0, gamma / st["gamma_prev"])
         alpha = jnp.where(
@@ -226,10 +234,9 @@ def pipecg(A, b, x0=None, *, maxiter=UNSET, tol=UNSET, M=UNSET,
                    gamma_prev=gamma, alpha_prev=alpha,
                    first=jnp.asarray(False), done=done,
                    iters=st["iters"] + (~done).astype(jnp.int32))
-        new = jax.tree.map(lambda nv, ov: jnp.where(st["done"], ov, nv), new, st)
         return new, jnp.sqrt(jnp.maximum(rr, 0.0))
 
-    st, hist = jax.lax.scan(step, state0, None, length=maxiter)
+    st, hist, _ = run_until_done(step, state0, maxiter)
     res = jnp.sqrt(jnp.maximum(dot(st["r"], st["r"]), 0.0))
     return SolveResult(x=st["x"], iters=st["iters"], res_norm=res,
                        res_history=hist)
@@ -260,9 +267,15 @@ def _pipecg_engine(A, b, x0=None, *, maxiter=100, tol=0.0, M=None,
                    precision=None) -> SolveResult:
     """PIPECG with the vector work delegated to an iteration engine.
 
-    Same scalar recurrences and masked-freeze semantics as the inline
-    ``pipecg``; only WHO performs the AXPYs/dots/SpMV differs.  The
-    engine's ``aux`` side-channel (checksum residual + ``<w, w>``) is
+    Same scalar recurrences and stopping rule as the inline ``pipecg``;
+    only WHO performs the AXPYs/dots/SpMV differs.  One right-hand side
+    at the default precision needs no masked update: the loop exits
+    after the step that sets ``done``.  Batched states freeze each
+    converged column (``jnp.where``) until every column is done, and a
+    storage-demoting policy freezes at the last good iterate on a
+    breakdown.
+
+    The engine's ``aux`` side-channel (checksum residual + ``<w, w>``) is
     recorded per iteration as ``SolveResult.detect_history`` and — when
     ``rr_tau > 0`` — drives adaptive residual replacement: a
     ``lax.cond``-guarded re-glue ``r = b - A x`` (plus operator images
@@ -320,6 +333,7 @@ def _pipecg_engine(A, b, x0=None, *, maxiter=100, tol=0.0, M=None,
     bb = jnp.sum(b * b, axis=-1)
     tol2 = jnp.asarray(tol, b.dtype) ** 2 * bb
     eps = abft.machine_eps(b.dtype)
+    freeze = gamma.ndim > 0 or not policy.is_default
 
     def _reglue(vecs_in):
         """Recompute r = b - A x, u = M r (+ images for 10-vector state).
@@ -344,7 +358,7 @@ def _pipecg_engine(A, b, x0=None, *, maxiter=100, tol=0.0, M=None,
         d2 = _rdot(w2, u2) if ip == "id" else _rdot(w2, w2)
         return rep, g2, d2, _rdot(r2, r2)
 
-    def step(st, _):
+    def step(st):
         alpha, beta = _pipecg_scalars(st)
         vecs, gamma_new, delta_new, rr, aux = eng.pipecg_iter(
             A_iter, M, ip, st["vecs"], alpha, beta)
@@ -388,6 +402,8 @@ def _pipecg_engine(A, b, x0=None, *, maxiter=100, tol=0.0, M=None,
             done = done | bad
 
         def frz(nv, ov):  # freeze converged systems (masked update)
+            if not freeze:  # the loop exits after the step that sets done
+                return nv
             m = (mask.reshape(mask.shape + (1,) * (nv.ndim - mask.ndim))
                  if nv.ndim > mask.ndim else mask)
             return jnp.where(m, ov, nv)
@@ -402,7 +418,7 @@ def _pipecg_engine(A, b, x0=None, *, maxiter=100, tol=0.0, M=None,
                    iters=st["iters"] + (~done).astype(jnp.int32))
         return new, (jnp.sqrt(jnp.maximum(rr, 0.0)), aux["chk"])
 
-    st, (hist, chk_hist) = jax.lax.scan(step, state0, None, length=maxiter)
+    st, (hist, chk_hist), _ = run_until_done(step, state0, maxiter)
     r = st["vecs"]["r"].astype(b.dtype)  # accum-width norm (no-op at fp32)
     res = jnp.sqrt(jnp.maximum(jnp.sum(r * r, axis=-1), 0.0))
     if hist.ndim == 2:  # batched: (maxiter, k) -> (k, maxiter)

@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.krylov.base import SolveResult, make_psum_dot
+from repro.core.krylov.base import (SolveResult, make_psum_dot,
+                                    run_until_done)
 from repro.core.krylov.operators import DiaMatrix
 from repro.core.krylov.options import PrecisionPolicy, as_policy
 from repro.core.noise.injection import NoiseHook
@@ -213,7 +214,7 @@ def sharded_pipecg_solve(offsets: Tuple[int, ...], bands_local, b_local, *,
     partial (k, 6) reduction row — the five Krylov partials plus the ABFT
     checksum partial ``1^T w' - c^T u'`` (kernels/checksum.py), which
     therefore rides the SAME carried all-reduce at zero extra collectives
-    — that is carried unreduced across the scan boundary; iteration i+1
+    — that is carried unreduced across the loop boundary; iteration i+1
     first issues its halo ppermutes (which depend only on the carried
     vectors), then finishes the reduction with ``psum`` and feeds the
     result to the scalar alpha/beta recurrence gating the kernel launch.
@@ -225,8 +226,16 @@ def sharded_pipecg_solve(offsets: Tuple[int, ...], bands_local, b_local, *,
 
     Because the reduction consumed at iteration i is the one INITIATED at
     iteration i-1, the residual history comes out shifted by one; a final
-    psum after the scan supplies ``||r_maxiter||`` and the history is
+    psum after the loop supplies the last residual and the history is
     rolled back into the naive solvers' alignment (hist[i] = ||r_{i+1}||).
+
+    The loop stops after the step whose psum'd ``||r||`` meets ``tol``
+    (:func:`~repro.core.krylov.base.run_until_done`): ``done`` comes from
+    the all-reduced row, so every shard takes the same exit.  ``tol = 0``
+    runs all ``maxiter`` steps.  One right-hand side at the default
+    precision needs no masked update; several freeze each converged
+    column until the last one is done.  History entries from the last
+    executed step on hold the final ``res_norm`` and checksum.
 
     ``M`` may be None (identity) or ``"jacobi"`` — in-kernel
     preconditioning only; opaque callables are rejected.  ``noise`` (a
@@ -282,7 +291,7 @@ def sharded_pipecg_solve(offsets: Tuple[int, ...], bands_local, b_local, *,
             f"or 'jacobi', got {M!r}")
 
     # loop-invariant operator extension: one ppermute per solve, hoisted
-    # out of the iteration scan by construction
+    # out of the iteration loop by construction
     bl, br = halo_exchange_cols(bands_local, halo, axis_name)
     bands_ext = jnp.concatenate([bl, bands_local, br], axis=-1)
     il, ir = halo_exchange_cols(invd, halo, axis_name)
@@ -302,7 +311,7 @@ def sharded_pipecg_solve(offsets: Tuple[int, ...], bands_local, b_local, *,
     wire_gram = policy.wire_gram == "int8"
     use_ef = policy.error_feedback
 
-    def mv(v):  # (k, n_local) halo matvec — init only; the scan uses the kernel
+    def mv(v):  # (k, n_local) halo matvec — init only; the loop uses the kernel
         lv, rv = halo_exchange_cols(v, halo, axis_name)
         v_ext = jnp.concatenate([lv, v, rv], axis=-1)
         y = jnp.zeros_like(v)
@@ -368,7 +377,7 @@ def sharded_pipecg_solve(offsets: Tuple[int, ...], bands_local, b_local, *,
         state0["gef"] = gef0
     if wire_halo:
         # sender-side error-feedback strips, one per edge per exchanged
-        # vector, carried across the scan
+        # vector, carried across the loop
         ef0 = jnp.zeros(r.shape[:-1] + (2 * halo,), r.dtype)
         state0.update(efu_l=ef0, efu_r=ef0, efp_l=ef0, efp_r=ef0)
     bb = jax.lax.psum(jnp.sum(B * B, axis=-1), axis_name)
@@ -377,8 +386,9 @@ def sharded_pipecg_solve(offsets: Tuple[int, ...], bands_local, b_local, *,
     # re-slices, re-pads or re-sums the loop-invariant operator
     blk, hop = kops.pipecg_halo_operator(offsets, bands_ext, invd_ext, x, u,
                                          block=block, n_shards=n_shards)
+    freeze = k_rhs > 1 or not policy.is_default
 
-    def step(st, _):
+    def step(st):
         # ---- halo exchange for THIS iteration's sweep: depends only on
         # the carried vectors, NOT on the pending reduction ----
         if wire_halo:
@@ -430,6 +440,8 @@ def sharded_pipecg_solve(offsets: Tuple[int, ...], bands_local, b_local, *,
         done = mask | (rr <= tol2)
 
         def frz(nv, ov):  # freeze converged systems (masked update)
+            if not freeze:  # the loop exits after the step that sets done
+                return nv
             m = (mask.reshape(mask.shape + (1,) * (nv.ndim - mask.ndim))
                  if nv.ndim > mask.ndim else mask)
             return jnp.where(m, ov, nv)
@@ -446,12 +458,15 @@ def sharded_pipecg_solve(offsets: Tuple[int, ...], bands_local, b_local, *,
             new["gef"] = gef if use_ef else st["gef"]
         return new, (jnp.sqrt(jnp.maximum(rr, 0.0)), chk)
 
-    st, (hist, chk_hist) = jax.lax.scan(step, state0, None, length=maxiter)
+    st, (hist, chk_hist), steps = run_until_done(step, state0, maxiter)
     red_fin = jax.lax.psum(st["red"], axis_name)
     res = jnp.sqrt(jnp.maximum(red_fin[:, 2], 0.0))
-    # roll the shifted history into the naive alignment hist[i] = ||r_{i+1}||
-    hist = jnp.concatenate([hist[1:], res[None]], axis=0)  # (maxiter, k)
-    chk_hist = jnp.concatenate([chk_hist[1:], red_fin[:, 5][None]], axis=0)
+    # roll the shifted history into the naive alignment hist[i] =
+    # ||r_{i+1}||; from the last executed step on it holds the final psum
+    rolled = (jnp.arange(maxiter) + 1 < steps)[:, None]   # (maxiter, 1)
+    hist = jnp.where(rolled, jnp.roll(hist, -1, axis=0), res)  # (maxiter, k)
+    chk_hist = jnp.where(rolled, jnp.roll(chk_hist, -1, axis=0),
+                         red_fin[:, 5])
     if batched:
         result = SolveResult(x=st["x"], iters=st["iters"], res_norm=res,
                              res_history=hist.T, detect_history=chk_hist.T)

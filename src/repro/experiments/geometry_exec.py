@@ -119,6 +119,15 @@ def _solver_body_counts(hlo: str) -> Dict:
     }
 
 
+def _executed_steps(fmt: str, iters, maxiter: int) -> int:
+    """Loop steps a sharded solve ran.  The 1-D body stops after the step
+    that sets ``done``: ``iters + 1`` steps, at most ``maxiter``.  The 2-D
+    and BSR bodies run every one of their ``maxiter`` scan steps."""
+    if fmt in ("dia2d", "bsr"):
+        return maxiter
+    return min(int(iters) + 1, maxiter)
+
+
 def _run_cells(cfg: Dict) -> Dict:
     """Execute every geometry cell in-process (the subprocess worker)."""
     import functools
@@ -175,15 +184,16 @@ def _run_cells(cfg: Dict) -> Dict:
             t0 = time.perf_counter()
             jax.block_until_ready(compiled(b).x)
             times.append(time.perf_counter() - t0)
-        t_iter = min(times) / maxiter
+        t_iter = min(times) / _executed_steps(fmt, out.iters, maxiter)
 
         hook = NoiseHook(Exponential(1.0), scale=noise_scale,
                          seed=seed + 13 * ci)
         noisy = jax.jit(functools.partial(solve, noise=hook))
         jax.block_until_ready(noisy(b).x)   # compile + first stalled run
         t0 = time.perf_counter()
-        jax.block_until_ready(noisy(b).x)
-        t_iter_noisy = (time.perf_counter() - t0) / maxiter
+        out_noisy = jax.block_until_ready(noisy(b))
+        t_iter_noisy = ((time.perf_counter() - t0)
+                        / _executed_steps(fmt, out_noisy.iters, maxiter))
 
         geom = _cell_geometry(fmt, grid, cfg, A)
         counts = _solver_body_counts(compiled.as_text())

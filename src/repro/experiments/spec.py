@@ -223,9 +223,9 @@ class CampaignSpec:
     geometry_bs:
         BSR block size of the ``bsr`` cells.
     geometry_maxiter / geometry_tol / geometry_repeats:
-        Iteration count (the scan always runs ``maxiter`` steps, so the
-        per-iteration time is wall / maxiter), freeze tolerance, and
-        timed repeats per cell.
+        Iteration bound, tolerance, and timed repeats per cell.  The
+        per-iteration time is wall / executed steps: the 1-D body stops
+        after iters + 1 steps, the 2-D and BSR bodies run ``maxiter``.
     geometry_noise_scale:
         Seconds per unit draw of the wall-clock ``NoiseHook`` stall in
         each cell's noisy twin run (exponential waits; the noise axis

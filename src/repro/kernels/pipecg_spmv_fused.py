@@ -189,6 +189,9 @@ def _sweep(offsets, bands, invd, csum, u, p, x, r, ab, edges, *, block: int,
         (lead, rows, LANE),
         (lambda j, i: (j, i, 0)) if batched else (lambda j, i: (0, i, 0)))
     rowsv = stencil.as_rows
+    args = (ab, *[rowsv(bands)] * 3, edges[0], *[rowsv(invd)] * 3, edges[1],
+            *[rowsv(u)] * 3, edges[2], *[rowsv(p)] * 3, edges[3],
+            rowsv(csum), rowsv(x), rowsv(r))
     outs = pl.pallas_call(
         kern,
         grid=(k_rhs, n // block),
@@ -203,10 +206,12 @@ def _sweep(offsets, bands, invd, csum, u, p, x, r, ab, edges, *, block: int,
                    jax.ShapeDtypeStruct((k_rhs, n_rows, LANE), u.dtype),
                    jax.ShapeDtypeStruct((k_rhs, n_rows, LANE), p.dtype),
                    jax.ShapeDtypeStruct((k_rhs, stencil.RED_ROWS, LANE), dt)],
+        # x' and r' overwrite x and r: each is read tile by tile, so a
+        # solve loop keeps them in place instead of copying them back into
+        # its carry (u and p are read as windows across tiles: not these)
+        input_output_aliases={len(args) - 2: 0, len(args) - 1: 1},
         interpret=interpret,
-    )(ab, *[rowsv(bands)] * 3, edges[0], *[rowsv(invd)] * 3, edges[1],
-      *[rowsv(u)] * 3, edges[2], *[rowsv(p)] * 3, edges[3],
-      rowsv(csum), rowsv(x), rowsv(r))
+    )(*args)
     vecs = tuple(o.reshape(k_rhs, n) for o in outs[:4])
     return vecs + (jnp.sum(outs[4][:, :NRED], axis=-1),)
 

@@ -40,7 +40,8 @@ _COMP_HDR = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*(?:\([^)]*\))?.*\{\s*$")
 _WHILE_RE = re.compile(r"while\(.*?condition=%?([\w.\-]+),\s*body=%?([\w.\-]+)")
 _CALL_RE = re.compile(r"(?:to_apply|calls|body|condition|branch_computations)="
                       r"\{?%?([\w.\-]+(?:,\s*%?[\w.\-]+)*)\}?")
-_CONST_RE = re.compile(r"[su](?:32|64)\[\]\s+constant\((\d+)\)")
+# an integer scalar constant, with or without a layout (TPU: ``{:T(128)}``)
+_CONST_RE = re.compile(r"[su](?:32|64)\[\](?:\{[^}]*\})?\s+constant\((\d+)\)")
 _TRIP_RE = re.compile(r'known_trip_count"?:\{"?n"?:"?(\d+)"?\}')
 
 

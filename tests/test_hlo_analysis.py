@@ -136,6 +136,14 @@ def test_trip_count_scaling():
     assert ag["count"] == 1 and ag["bytes"] == 1024
 
 
+def test_while_bound_with_tpu_layout():
+    """A while loop with an early exit has no known trip count; its bound
+    is the condition's constant, which TPU HLO prints with a layout."""
+    hlo = FAKE_HLO.replace("%c = s32[] constant(28)",
+                           "%c = s32[]{:T(128)} constant(28)")
+    assert analyze_collectives(hlo)["while_trip_counts"] == {"body.2": 28}
+
+
 def test_real_compiled_scan_trip_count():
     """A scanned computation compiled on CPU exposes its trip count."""
     def f(x):

@@ -79,6 +79,36 @@ def _band_shaped_in_loops(text: str) -> list:
                         r"bitcast|parameter)", ln)]
 
 
+def _loop_computations(text: str) -> dict:
+    """Every computation a while body runs: the bodies and, transitively,
+    the fusions and calls inside them."""
+    comps = hlo_analysis._split_computations(text)
+    todo = [m.group(2) for m in hlo_analysis._WHILE_RE.finditer(text)]
+    seen = {}
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen[name] = comps[name]
+        for ln in comps[name]:
+            for cm in hlo_analysis._CALL_RE.finditer(ln):
+                todo += [c.lstrip("%") for c in re.split(r",\s*", cm.group(1))]
+    return seen
+
+
+def _vector_selects_in_loops(text: str, n: int) -> list:
+    """``select`` ops inside a while body whose result holds n or more
+    elements: a masked update of whole vectors."""
+    hits = []
+    for lines in _loop_computations(text).values():
+        for ln in lines:
+            m = re.match(r"%?[\w.\-]+ = \w+\[([\d,]*)\]\S* select\(", ln)
+            dims = [int(d) for d in m.group(1).split(",") if d] if m else []
+            if m and np.prod(dims) >= n:
+                hits.append(ln)
+    return hits
+
+
 def test_spmv_dia_compiles(one_chip):
     text = _compiled_text(
         lambda bands, x_ext: ops.spmv_dia_ext(OFFSETS, bands, x_ext, HALO),
@@ -117,6 +147,38 @@ def test_fused_solve_compiles(one_chip):
     assert "tpu_custom_call" in text
     assert "while(" in text
     assert not _band_shaped_in_loops(text)
+
+
+@pytest.mark.parametrize("k_rhs", [1, 2], ids=["one-rhs", "batched"])
+def test_fused_solve_loop_stops_at_convergence(one_chip, k_rhs):
+    """The one-chip fused solve is a while loop bounded by maxiter.  With
+    one right-hand side at the default precision it exits after the
+    converging step, so its body holds no masked update of a vector; a
+    batch still freezes each converged column."""
+    from repro.core.krylov import pipecg, pipecg_multi
+    from repro.core.krylov.operators import DiaMatrix
+    from repro.core.krylov.options import SolverOptions
+
+    maxiter = 50
+    if k_rhs == 1:
+        opts = SolverOptions(engine="fused", M="jacobi", maxiter=maxiter,
+                             tol=1e-6)
+        fn = lambda bands, b: pipecg(DiaMatrix(OFFSETS, bands), b,
+                                     options=opts)
+        rhs = _spec(one_chip, N)
+    else:
+        fn = lambda bands, b: pipecg_multi(DiaMatrix(OFFSETS, bands), b,
+                                           maxiter=maxiter, tol=1e-6,
+                                           M="jacobi", engine="fused")
+        rhs = _spec(one_chip, k_rhs, N)
+    text = _compiled_text(fn, _spec(one_chip, len(OFFSETS), N), rhs)
+    trips = hlo_analysis.analyze_collectives(text)["while_trip_counts"]
+    assert list(trips.values()) == [maxiter], trips
+    selects = _vector_selects_in_loops(text, N)
+    if k_rhs == 1:
+        assert not selects, selects
+    else:
+        assert selects
 
 
 def test_pipecg_fused_update_kernel_compiles(one_chip):
@@ -167,3 +229,8 @@ def test_sharded_fused_solve_compiles_on_four_chips(topo, monkeypatch):
     assert overlap["overlap_ok"], overlap
     assert [b["all_reduce"] for b in overlap["bodies"].values()] == [1]
     assert not _band_shaped_in_loops(text)
+    # the loop stops at convergence, bounded by maxiter, with no masked
+    # update of a shard's vectors
+    trips = hlo_analysis.analyze_collectives(text)["while_trip_counts"]
+    assert list(trips.values()) == [50], trips
+    assert not _vector_selects_in_loops(text, N)
