@@ -10,7 +10,8 @@ Two regimes, mirroring how the rest of the repo treats the CPU container:
   caller-supplied ``probe(block) -> jittable thunk``.
 
 Choices are cached per (kind, n, dtype, backend, min_block, n_shards,
-k_rhs) for the process lifetime — the sharding degree and RHS batch
+k_rhs, and where set the storage dtype, format and band count) for the
+process lifetime — the sharding degree and RHS batch
 change both the local row count and how the resident operand reads
 amortize, so they are part of the key.  ``save_cache`` / ``load_cache``
 persist the table as JSON (``results/autotune_cache.json`` by default)
@@ -56,14 +57,15 @@ def cache_stats() -> Dict[str, int]:
 
 def _key(kind: str, n: int, dtype, backend: str, min_block: int,
          n_shards: int, k_rhs: int, dtype_storage=None,
-         fmt: Optional[str] = None) -> str:
+         fmt: Optional[str] = None, n_bands: Optional[int] = None) -> str:
     """JSON-stable cache key: backend + full shape + dtype signature.
 
     ``dtype_storage`` names the carried-vector storage dtype of a mixed
-    PrecisionPolicy and ``fmt`` a non-default operator format ("bsr");
-    each is appended only when set, so the keys of pure fp32/fp64 DIA
-    sweeps (and every previously persisted cache file) are unchanged —
-    the append-only convention for extending this key.
+    PrecisionPolicy, ``fmt`` a non-default operator format ("bsr") and
+    ``n_bands`` the band count of a kernel whose footprint grows with it
+    (``bands=5``); each is appended only when set, so the keys of every
+    other kernel (and their persisted cache entries) are unchanged — the
+    append-only convention for extending this key.
     """
     parts = [kind, n, jnp.dtype(dtype).name, backend, min_block, n_shards,
              k_rhs]
@@ -71,6 +73,8 @@ def _key(kind: str, n: int, dtype, backend: str, min_block: int,
         parts.append(jnp.dtype(dtype_storage).name)
     if fmt is not None:
         parts.append(str(fmt))
+    if n_bands is not None:
+        parts.append(f"bands={int(n_bands)}")
     return "|".join(str(v) for v in parts)
 
 
@@ -134,7 +138,8 @@ def best_block(kind: str, n: int, dtype, *,
                probe: Optional[Callable[[int], Callable[[], jax.Array]]] = None,
                backend: Optional[str] = None,
                n_shards: int = 1, k_rhs: int = 1,
-               dtype_storage=None, fmt: Optional[str] = None) -> int:
+               dtype_storage=None, fmt: Optional[str] = None,
+               n_bands: Optional[int] = None) -> int:
     """Pick a block size for a tiled kernel sweep.
 
     kind            — cache namespace (e.g. "pipecg_spmv", "spmv_dia")
@@ -156,12 +161,16 @@ def best_block(kind: str, n: int, dtype, *,
     fmt             — operator format when not the default DIA ("bsr");
                       part of the cache key (block units and resident
                       footprints differ per format)
+    n_bands         — band count where ``candidates`` were bounded by a
+                      footprint that grows with it; part of the cache key
+                      so a wider operator never reuses a narrower one's
+                      block
     """
     backend = backend or jax.default_backend()
     # min_block is part of the key: the same (kind, n) tuned for a narrow
     # band must not hand its block to a caller with a wider halo floor
     key = _key(kind, n, dtype, backend, min_block, n_shards, k_rhs,
-               dtype_storage=dtype_storage, fmt=fmt)
+               dtype_storage=dtype_storage, fmt=fmt, n_bands=n_bands)
     if key in _CACHE:
         _STATS["hits"] += 1
         return _CACHE[key]

@@ -216,25 +216,62 @@ def fused_dots(V, z):
     return _fd.fused_dots(V, z, block=block, interpret=_interpret())
 
 
-def _sweep_block(kind, offsets, bands, x, u, **key):
-    """Autotuned block of the single-sweep PIPECG kernel.
+# scoped VMEM a Mosaic kernel may use without raising its limit: the
+# default of the v5e (and v4; a v6e grants 32 MiB)
+SCOPED_VMEM_BYTES = 16 << 20
+# the sweep's least block, 64 rows of 128 lanes (or the least multiple
+# of the halo rows above it), taken even where the footprint model
+# would not fit it
+MIN_SWEEP_ROWS = 64
+
+
+def _sweep_rows(n: int, hb: int, fits) -> list:
+    """Candidate block rows of the PIPECG sweep over ``n`` elements.
+
+    Multiples of ``hb`` from ``MIN_SWEEP_ROWS`` (or the whole vector,
+    where it is shorter) up to the whole vector, as far as ``fits(rows)``
+    holds.  Where one of them divides ``n``, only those: a larger block
+    never pads a vector that a smaller one tiles exactly.
+    """
+    whole = stencil.legal_block(n, hb) // stencil.LANE
+    lo = min(-(-MIN_SWEEP_ROWS // hb) * hb, whole)
+    rows = [lo]
+    for r in range(lo + hb, whole + 1, hb):
+        if not fits(r):
+            break
+        rows.append(r)
+    exact = [r for r in rows if n % (r * stencil.LANE) == 0]
+    return exact or rows
+
+
+def _sweep_block(kind, offsets, bands, inv_diag, x, u, **key):
+    """Block of the single-sweep PIPECG kernel, from its halo and VMEM.
 
     Per row the sweep reads x, r, c = A^T 1 and writes x, r, u, p; u, p,
-    the bands and diag^-1 are read over each tile plus 2*hb halo rows.
-    r/u/p count at their storage dtype, the operator at its own.
+    the bands and diag^-1 are read over each tile plus 2*hb halo rows,
+    so a larger tile re-reads fewer halo rows.  The candidates are the
+    blocks whose footprint (``sweep_vmem_bytes``) fits the default scoped
+    VMEM; the words model then takes the one that moves the least, the
+    largest where none pads.  r/u/p count at their storage dtype, the
+    operator at its own.
     """
     from repro.kernels import autotune
 
-    hb = _ps.halo_rows(offsets, bands.dtype, x.dtype, u.dtype)
+    hb = _ps.halo_rows(offsets, bands.dtype, inv_diag.dtype, x.dtype, u.dtype)
+    nb = bands.shape[0]
     rs = _rel_words(u.dtype, x.dtype)        # carried r/u/p storage
     ro = _rel_words(bands.dtype, x.dtype)    # operator storage
-    windowed = 2 * rs + (bands.shape[0] + 1) * ro
+    windowed = 2 * rs + (nb + 1) * ro
+    fits = lambda r: _ps.sweep_vmem_bytes(
+        r, hb, nb, x.dtype, u.dtype, bands.dtype) <= SCOPED_VMEM_BYTES
+    rows = _sweep_rows(x.shape[-1], hb, fits)
     return autotune.best_block(
         kind, x.shape[-1], x.dtype,
         words_per_row=2.0 + 4.0 * rs + ro + windowed,
         step_words=2 * hb * stencil.LANE * windowed,
         min_block=hb * stencil.LANE,
-        dtype_storage=_storage_key(u.dtype, x.dtype), **key)
+        candidates=[r * stencil.LANE for r in rows],
+        dtype_storage=_storage_key(u.dtype, x.dtype), n_bands=nb, **key)
 
 
 def pipecg_sweep_operator(offsets: Tuple[int, ...], bands, inv_diag, x, u,
@@ -243,11 +280,12 @@ def pipecg_sweep_operator(offsets: Tuple[int, ...], bands, inv_diag, x, u,
 
     ``bands`` (n_bands, n) / ``inv_diag`` (n,) are the whole operator;
     ``x`` ((n,) or (k, n)) and ``u`` give the solve and storage dtypes
-    (r and p are carried in u's).  The default block comes from the
-    autotuner.  Returns ``(block, op)`` for :func:`pipecg_sweep_step`.
+    (r and p are carried in u's).  The default block is sized from the
+    sweep's halo and VMEM footprint (:func:`_sweep_block`).  Returns
+    ``(block, op)`` for :func:`pipecg_sweep_step`.
     """
     if block is None:
-        block = _sweep_block("pipecg_spmv", offsets, bands, x, u)
+        block = _sweep_block("pipecg_spmv", offsets, bands, inv_diag, x, u)
     hb = _ps.halo_rows(offsets, bands.dtype, inv_diag.dtype, x.dtype, u.dtype)
     block = stencil.legal_block(min(block, x.shape[-1]), hb)
     return block, _ps.local_operator(offsets, bands, inv_diag, block=block,
@@ -295,8 +333,8 @@ def pipecg_halo_operator(offsets: Tuple[int, ...], bands_ext, invd_ext, x, u,
     ``bands_ext`` / ``invd_ext`` are the halo-extended local operator;
     ``x`` (k, n_local) and ``u`` give the RHS count and the solve and
     storage dtypes (r and p are carried in u's).  The default block is
-    autotuned on (backend, n_local, n_shards, k_rhs) — repeated campaign
-    runs reuse the on-disk cache (kernels/autotune.py).  Returns
+    sized from the sweep's halo and VMEM footprint (:func:`_sweep_block`)
+    and cached on (backend, n_local, n_shards, k_rhs, band count).  Returns
     ``(block, op)`` for :func:`pipecg_spmv_halo_step`.
     """
     k_rhs, n = x.shape
@@ -306,8 +344,8 @@ def pipecg_halo_operator(offsets: Tuple[int, ...], bands_ext, invd_ext, x, u,
             f"local shard of {n} rows is narrower than the 2*halo={2*halo} "
             "stencil reach; use fewer shards or a wider local block")
     if block is None:
-        block = _sweep_block("pipecg_spmv_halo", offsets, bands_ext, x, u,
-                             n_shards=n_shards, k_rhs=k_rhs)
+        block = _sweep_block("pipecg_spmv_halo", offsets, bands_ext,
+                             invd_ext, x, u, n_shards=n_shards, k_rhs=k_rhs)
     hb = _ps.halo_rows(offsets, bands_ext.dtype, invd_ext.dtype, x.dtype,
                        u.dtype)
     block = stencil.legal_block(min(block, n), hb)

@@ -160,6 +160,34 @@ def halo_rows(offsets: Sequence[int], *dtypes) -> int:
     return stencil.halo_rows(halo, 2, stencil.sublanes(*dtypes))
 
 
+# window-sized values the kernel body holds at its peak, at the
+# accumulation dtype: u, p', s', one band's window, the shifted p' with
+# the two rolls and the lane select that build it, and their product
+WINDOW_TEMPS = 8
+
+
+def sweep_vmem_bytes(rows: int, hb: int, n_bands: int, acc, storage,
+                     operator) -> int:
+    """Scoped VMEM of one grid step of :func:`_sweep` at ``rows`` rows.
+
+    Every input and output block is double-buffered.  The windowed
+    operands (the bands, diag^-1, u and p) each bring a centre block,
+    two ``hb``-row halo blocks and the ``2*hb``-row edge operand; c, x
+    and r one tile each; the outputs x', r', u', p' one tile each and
+    the reduction row.  On top come ``WINDOW_TEMPS`` values of the
+    kernel body over the ``rows + 2*hb`` window.  ``acc`` is x's dtype,
+    ``storage`` that of r, u and p, ``operator`` that of the bands,
+    diag^-1 and c.  The v5e's Mosaic compile takes every block this
+    counts within its default scoped VMEM (tests/test_tpu_compile.py).
+    """
+    a, s, o = (jnp.dtype(d).itemsize for d in (acc, storage, operator))
+    windowed = ((n_bands + 1) * o + 2 * s) * (rows + 4 * hb)
+    tiles = (o + a + s) * rows
+    outs = (a + 3 * s) * rows + a * stencil.RED_ROWS
+    temps = WINDOW_TEMPS * a * (rows + 2 * hb)
+    return LANE * (2 * (windowed + tiles + outs) + temps)
+
+
 def _sweep(offsets, bands, invd, csum, u, p, x, r, ab, edges, *, block: int,
            n_valid: int = None, interpret: bool = False
            ) -> Tuple[jnp.ndarray, ...]:

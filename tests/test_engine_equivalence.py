@@ -35,6 +35,7 @@ from repro.core.krylov import (
     pipecg_multi,
     pipecr,
     glen_law_band,
+    laplacian_2d,
     tridiagonal_laplacian,
 )
 
@@ -228,6 +229,10 @@ def _manual_sharded_step(A, invd, x, r, u, p, alpha, beta, shards,
     # reduction mask (halo rows leak real data into the pad region)
     (520, 2, 8, 32, tridiagonal_laplacian),
     (480, 1, 4, None, lambda n: glen_law_band(n, bandwidth=10)),
+    # several tiles per shard: windows cross tile boundaries inside a
+    # shard as well as at its edges (the default block takes one tile)
+    (8192, 2, 2, 1024, tridiagonal_laplacian),
+    (16384, 1, 2, 2048, lambda n: laplacian_2d(1024, n // 1024)),
 ])
 def test_sharded_halo_kernel_chunks_match_full_sweep(n, k, shards, block, mk):
     """Per-chunk halo kernel == full-vector single-sweep kernel: the halo
@@ -244,6 +249,37 @@ def test_sharded_halo_kernel_chunks_match_full_sweep(n, k, shards, block, mk):
                                        alpha, beta)
     got = _manual_sharded_step(A, invd, x, r, u, p, alpha, beta, shards,
                                block=block)
+    for g, w in zip(got, want):
+        scale = float(jnp.max(jnp.abs(w))) + 1e-30
+        assert float(jnp.max(jnp.abs(g - w))) / scale < 1e-12
+
+
+@pytest.mark.parametrize("mk,k", [
+    (lambda: tridiagonal_laplacian(8192), 1),
+    (lambda: laplacian_2d(1024, 16), 2),      # +-1024 offsets: hb = 16
+], ids=["tridiagonal", "laplacian_2d"])
+def test_fused_sweep_across_tiles_matches_default_block(mk, k):
+    """A forced small block splits the one-device sweep into several
+    tiles whose windows cross tile boundaries; it gives the default
+    block's result."""
+    from repro.kernels import ops as kops
+
+    A = mk()
+    n = A.bands.shape[1]
+    rng = np.random.default_rng(11)
+    x, r, u, p = (jnp.asarray(rng.standard_normal((k, n))) for _ in range(4))
+    alpha = jnp.asarray(rng.standard_normal(k))
+    beta = jnp.asarray(rng.standard_normal(k))
+    invd = 1.0 / A.bands[A.offsets.index(0)]
+    hb = kops._ps.halo_rows(A.offsets, x.dtype)
+    small, op_small = kops.pipecg_sweep_operator(A.offsets, A.bands, invd,
+                                                 x, u, block=hb * 128)
+    block, op = kops.pipecg_sweep_operator(A.offsets, A.bands, invd, x, u)
+    assert n // small >= 4 and block > small
+    got = kops.pipecg_sweep_step(A.offsets, op_small, x, r, u, p, alpha,
+                                 beta, block=small)
+    want = kops.pipecg_sweep_step(A.offsets, op, x, r, u, p, alpha, beta,
+                                  block=block)
     for g, w in zip(got, want):
         scale = float(jnp.max(jnp.abs(w))) + 1e-30
         assert float(jnp.max(jnp.abs(g - w))) / scale < 1e-12

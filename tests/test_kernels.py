@@ -136,3 +136,86 @@ def test_no_interpreted_fallback_off_cpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     with pytest.raises(RuntimeError, match="gpu"):
         ops._interpret()
+
+
+# -- the PIPECG sweep's block rule --------------------------------------------
+
+EX23 = (-1, 0, 1)
+POISSON2D = (-2048, -1, 0, 1, 2048)     # 2048 x 4096 grid, 5-point stencil
+
+
+def _rule_block(offsets, n, k=1, storage=jnp.float32, acc=jnp.float32,
+                kind="pipecg_spmv", **key):
+    """The rule's block and window rows for these shapes (no arrays)."""
+    from repro.kernels import pipecg_spmv_fused as ps
+
+    S = jax.ShapeDtypeStruct
+    nb = len(offsets)
+    block = ops._sweep_block(kind, offsets, S((nb, n), storage),
+                             S((n,), storage), S((k, n), acc),
+                             S((k, n), storage), **key)
+    hb = ps.halo_rows(offsets, storage, acc)
+    # what the sweep runs: pipecg_sweep_operator / pipecg_halo_operator
+    return ops.stencil.legal_block(min(block, n), hb), hb
+
+
+@pytest.mark.parametrize("offsets,n,k,storage,acc,key", [
+    (EX23, 2 ** 22, 1, jnp.float32, jnp.float32, {}),
+    (POISSON2D, 2 ** 23, 1, jnp.float32, jnp.float32, {}),
+    (POISSON2D, 2 ** 23, 8, jnp.float32, jnp.float32, {}),
+    (POISSON2D, 2 ** 23, 1, jnp.bfloat16, jnp.float32, {}),
+    (EX23, 2 ** 20, 1, jnp.float32, jnp.float32,
+     dict(kind="pipecg_spmv_halo", n_shards=4)),
+    (EX23, 2 ** 16, 1, jnp.float64, jnp.float64, {}),
+    (EX23, 4096, 1, jnp.float64, jnp.float64, {}),
+    (EX23, 100003, 1, jnp.float32, jnp.float32, {}),
+], ids=["ex23", "poisson2d", "poisson2d-k8", "poisson2d-bf16", "ex23-4chip",
+        "ex23-f64-cpu", "short", "ragged"])
+def test_sweep_block_rule(offsets, n, k, storage, acc, key):
+    """The block is whole windows of hb rows, at least 64 rows (or the
+    whole vector), at most the vector, within the default scoped VMEM by
+    its footprint model, and pads no vector that 64-row tiles cover
+    exactly."""
+    from repro.kernels import autotune, pipecg_spmv_fused as ps
+
+    autotune.clear_cache()
+    block, hb = _rule_block(offsets, n, k, storage, acc, **key)
+    rows, lane = block // 128, 128
+    n_rows = -(-n // lane)
+    assert rows % hb == 0
+    assert rows >= min(ops.MIN_SWEEP_ROWS, -(-n_rows // hb) * hb)
+    assert block <= ops.stencil.legal_block(n, hb)
+    if rows > ops.MIN_SWEEP_ROWS:
+        assert ps.sweep_vmem_bytes(rows, hb, len(offsets), acc, storage,
+                                   storage) <= ops.SCOPED_VMEM_BYTES
+    if n % (ops.MIN_SWEEP_ROWS * lane) == 0:
+        assert n % block == 0
+
+
+def test_sweep_block_grows_where_vmem_allows():
+    """On the 2-D stencil's 32-row halos the tile is far past 64 rows,
+    and bf16 storage, with the smaller footprint, takes a larger one."""
+    from repro.kernels import autotune
+
+    autotune.clear_cache()
+    f32, hb = _rule_block(POISSON2D, 2 ** 23)
+    bf16, _ = _rule_block(POISSON2D, 2 ** 23, storage=jnp.bfloat16)
+    assert hb == 32
+    assert f32 // 128 >= 256
+    assert bf16 > f32
+
+
+def test_sweep_block_cache_keeps_band_counts_apart():
+    """A 3-band and a 5-band operator of equal n and hb share no cache
+    entry: the wider one's footprint is larger."""
+    from repro.kernels import autotune, pipecg_spmv_fused as ps
+
+    five = (-2, -1, 0, 1, 2)
+    assert ps.halo_rows(EX23, jnp.float32) == ps.halo_rows(five, jnp.float32)
+    autotune.clear_cache()
+    _rule_block(EX23, 2 ** 20)
+    _rule_block(five, 2 ** 20)
+    assert autotune.cache_stats()["misses"] == 2
+    keys = [k for k in autotune._CACHE if k.startswith("pipecg_spmv|")]
+    assert sorted(k.rsplit("|", 1)[1] for k in keys) == ["bands=3",
+                                                         "bands=5"]

@@ -5,8 +5,12 @@ installed with JAX, so these tests refuse what interpret mode accepts:
 unaligned dynamic loads, ``dynamic_slice`` on in-register values, scalar
 stores into VMEM, and kernels whose blocks overflow the scoped VMEM.
 Nothing runs; a pass says only that the chip's compiler takes the
-program.  Sizes are a deployment's per-chip share (2^20 fp32 rows), at
-the block the autotuner picks, and the largest rows per chip that fit.
+program.  Sizes are a deployment's per-chip share (2^20 fp32 rows), the
+largest rows per chip that fit, and the 2-D benchmark operator (2^23
+rows, +-2048 offsets), each at the block the program picks.  The PIPECG
+sweep's block is the largest whose modeled footprint
+(``pipecg_spmv_fused.sweep_vmem_bytes``) fits the default scoped VMEM of
+16 MiB, so these compiles also check that model against Mosaic.
 
 The topology is described inside a module fixture, never at import:
 only one process may hold the TPU library at a time.
@@ -27,6 +31,7 @@ from repro.launch import hlo_analysis
 N = 2 ** 20
 OFFSETS = (-1, 0, 1)          # the ex23 tridiagonal Laplacian
 HALO = 1
+OFFSETS_2D = (-2048, -1, 0, 1, 2048)   # ex2's 5-point stencil, 2048 x 4096
 
 
 @pytest.fixture(scope="module")
@@ -116,21 +121,95 @@ def test_spmv_dia_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("k_rhs,n", [(1, N), (8, N), (1, 2 ** 26),
-                                     (8, 2 ** 24)],
-                         ids=["1", "8", "1-largest", "8-largest"])
-def test_pipecg_spmv_fused_compiles(one_chip, k_rhs, n):
+@pytest.mark.parametrize("k_rhs,n,offsets,storage", [
+    (1, N, OFFSETS, jnp.float32), (8, N, OFFSETS, jnp.float32),
+    (1, 2 ** 26, OFFSETS, jnp.float32), (8, 2 ** 24, OFFSETS, jnp.float32),
+    (1, 2 ** 23, OFFSETS_2D, jnp.float32),
+    (8, 2 ** 23, OFFSETS_2D, jnp.float32),
+    (1, 2 ** 23, OFFSETS_2D, jnp.bfloat16),
+], ids=["1", "8", "1-largest", "8-largest", "2d", "2d-8", "2d-bf16"])
+def test_pipecg_spmv_fused_compiles(one_chip, k_rhs, n, offsets, storage):
     """Single-chip sweep; k = 8 is the SolverServer's multi-RHS batch.
 
     The largest rows per chip that compile: nothing is VMEM-resident, so
-    the bound is the 16 GB of HBM (k = 8 at 2^26 rows exceeds it).
+    the bound is the 16 GB of HBM (k = 8 at 2^26 rows exceeds it).  The
+    2-D cases are the benchmark's operator with its 32-row halos, in
+    fp32 and with bf16 storage of r, u, p and the operator (x and the
+    reductions stay fp32), at the block the footprint model picks.
     """
     vec = _spec(one_chip, k_rhs, n)
+    store = jax.ShapeDtypeStruct((k_rhs, n), storage, sharding=one_chip)
+    op = lambda *shape: jax.ShapeDtypeStruct(shape, storage,
+                                             sharding=one_chip)
     text = _compiled_text(
-        lambda *a: ops.pipecg_spmv_fused_step(OFFSETS, *a),
-        _spec(one_chip, len(OFFSETS), n), _spec(one_chip, n),
-        vec, vec, vec, vec, _spec(one_chip, k_rhs), _spec(one_chip, k_rhs))
+        lambda *a: ops.pipecg_spmv_fused_step(offsets, *a),
+        op(len(offsets), n), op(n), vec, store, store, store,
+        _spec(one_chip, k_rhs), _spec(one_chip, k_rhs))
     assert "tpu_custom_call" in text
+
+
+def test_pipecg_sweep_block_on_the_2d_operator(one_chip):
+    """The rule sizes the 2-D operator's tile well past its 2 x 32 halo
+    rows: at least 256 rows of 128 lanes, dividing the vector."""
+    from repro.kernels import autotune
+
+    autotune.clear_cache()
+    n = 2 ** 23
+    vec = _spec(one_chip, 1, n)
+    block = ops._sweep_block("pipecg_spmv", OFFSETS_2D,
+                             _spec(one_chip, len(OFFSETS_2D), n),
+                             _spec(one_chip, n), vec, vec)
+    assert block // 128 >= 256 and n % block == 0, block
+
+
+def _model_rows(offsets, storage, budget):
+    """The largest block rows the sweep's footprint model fits in
+    ``budget`` bytes (fp32 accumulation)."""
+    from repro.kernels import pipecg_spmv_fused as ps
+
+    hb = ps.halo_rows(offsets, storage, jnp.float32)
+    fits = lambda r: ps.sweep_vmem_bytes(r, hb, len(offsets), jnp.float32,
+                                         storage, storage) <= budget
+    rows = hb
+    while fits(rows + hb):
+        rows += hb
+    return rows
+
+
+def _compile_sweep_at(one_chip, offsets, storage, rows, n=2 ** 20):
+    store = lambda *shape: jax.ShapeDtypeStruct(shape, storage,
+                                                sharding=one_chip)
+    vec = _spec(one_chip, 1, n)
+    return _compiled_text(
+        lambda *a: ops.pipecg_spmv_fused_step(offsets, *a, block=rows * 128),
+        store(len(offsets), n), store(n), vec, store(1, n), store(1, n),
+        store(1, n), _spec(one_chip, 1), _spec(one_chip, 1))
+
+
+@pytest.mark.parametrize("offsets,storage", [
+    (OFFSETS, jnp.float32), (OFFSETS_2D, jnp.float32),
+    (OFFSETS_2D, jnp.bfloat16), ((-256, -1, 0, 1, 256), jnp.float32),
+], ids=["ex23", "2d", "2d-bf16", "5-band-hb8"])
+def test_sweep_footprint_model_fits_what_mosaic_accepts(one_chip, offsets,
+                                                        storage):
+    """Mosaic takes the largest block the footprint model fits in the
+    default scoped VMEM: the rule never picks a block that overflows."""
+    rows = _model_rows(offsets, storage, ops.SCOPED_VMEM_BYTES)
+    assert rows >= ops.MIN_SWEEP_ROWS
+    assert "tpu_custom_call" in _compile_sweep_at(one_chip, offsets, storage,
+                                                  rows)
+
+
+def test_sweep_footprint_model_refuses_what_mosaic_refuses(one_chip):
+    """A 2048-row block of the 2-D sweep overflows the scoped VMEM, and
+    the model does not fit it either."""
+    from repro.kernels import pipecg_spmv_fused as ps
+
+    assert ps.sweep_vmem_bytes(2048, 32, 5, jnp.float32, jnp.float32,
+                               jnp.float32) > ops.SCOPED_VMEM_BYTES
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compile_sweep_at(one_chip, OFFSETS_2D, jnp.float32, 2048,
+                          n=2 ** 23)
 
 
 def test_fused_solve_compiles(one_chip):
